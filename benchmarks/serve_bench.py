@@ -513,7 +513,7 @@ def bench_sharded(*, slots: int = SHARDED_SLOTS, max_len: int = 32,
     except ImportError:          # run as a script: benchmarks/ is sys.path[2]
         from train_bench import bench_config
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel import context as pctx_mod
     from repro.serve.engine import ServeEngine
 
@@ -597,6 +597,9 @@ def sharded_rows_subprocess() -> list:
     parent's jax device count is locked at first init)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # the child measures on a virtual CPU mesh; it must never reach for
+    # an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--sharded-only"],
         capture_output=True, text=True, env=env, timeout=1200,

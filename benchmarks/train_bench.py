@@ -50,7 +50,7 @@ def bench_impl(impl: str, *, batch: int = 8, seq: int = 32, steps: int = 4,
     import jax
     import jax.numpy as jnp
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel import context as pctx_mod
     from repro.parallel import overlap
     from repro.train.trainer import Trainer, TrainConfig
@@ -114,6 +114,9 @@ def suite():
     8-device host platform never leaks into the parent's jax."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # the child measures on a virtual CPU mesh; it must never reach for
+    # an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     outf = "BENCH_train.json"
     r = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--out", outf],

@@ -10,7 +10,7 @@ default used here) is the zero-config single-device path. To shard the
 same deployment over a device mesh, build a ``ParallelCtx`` and hand it
 to the pools — e.g. with 8 devices::
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel import context as pctx_mod
     dctx = pctx_mod.ParallelCtx(mesh=make_mesh((1, 4), ("data", "model")),
                                 dp_axes=("data",), moe_impl="ep_dedup")

@@ -68,15 +68,16 @@ def pages_for(tokens: int, page_size: int) -> int:
 def pool_model_axes(leaf_name: str, ndim: int):
     """Model-axis shardability of one pool leaf, declared by name (the
     paged analogue of ``Model.paged_aux_axes`` / sharding's name-driven
-    ``_CACHE_AXES``): GQA K/V pools ``(layers, P+1, page, KV, hd)`` can
-    shard their KV-head axis over the model axis; per-token scale
+    ``_CACHE_AXES``): GQA K/V pools ``(layers, P+1, page, KV*hd)`` can
+    shard their flat head axis over the model axis (whole heads per
+    device when KV divides by the axis size); per-token scale
     sidebands ``(layers, P+1, page)`` and the MLA latent/rope pools (no
     head axis — the latent is shared by every head, which is the whole
     point of MLA) replicate. The *page* axis is never sharded: admission
     scatters and decode gathers index physical page ids, and splitting
     those across devices would turn every table lookup into a collective.
     """
-    if leaf_name in ("k", "v") and ndim == 5:
+    if leaf_name in ("k", "v") and ndim == 4:
         return 3
     return None
 
@@ -199,7 +200,7 @@ def table_gather(pool: jax.Array, table: jax.Array) -> jax.Array:
 
 
 def gather_dequant(pool: jax.Array, scale_pool: jax.Array,
-                   table: jax.Array, vec_ndim: int = 1) -> jax.Array:
+                   table: jax.Array) -> jax.Array:
     """Fused ``table_gather`` + ``dequantize_vecs`` (fp32 out).
 
     Bit-identical to the unfused pair, but the E4M3 pool is bitcast to
@@ -213,8 +214,7 @@ def gather_dequant(pool: jax.Array, scale_pool: jax.Array,
         vals = e4m3_decode(table_gather(u8, table))
     else:
         vals = table_gather(pool, table).astype(jnp.float32)
-    s = table_gather(scale_pool, table)
-    return vals * s.reshape(s.shape + (1,) * vec_ndim)
+    return vals * table_gather(scale_pool, table)[..., None]
 
 
 # ---------------------------------------------------------------------------
@@ -223,12 +223,12 @@ def gather_dequant(pool: jax.Array, scale_pool: jax.Array,
 
 
 def entries_to_pages(leaf: jax.Array, page_size: int, storage: str,
-                     store_dtype, vec_ndim: int = 1) -> Dict[str, jax.Array]:
+                     store_dtype) -> Dict[str, jax.Array]:
     """Reshape a batch-1 prefill cache leaf into quantized page data.
 
-    leaf: ``(n, 1, T, ...)`` with ``T`` the (bucket) prompt capacity laid
+    leaf: ``(n, 1, T, width)`` with ``T`` the (bucket) prompt capacity laid
     out position-identically (no wrap — guaranteed for ``T >= length``).
-    Returns ``{"q": (n, T//page, page, ...)}`` plus ``{"scale": ...}`` for
+    Returns ``{"q": (n, T//page, page, width)}`` plus ``{"scale": ...}`` for
     fp8 storage. Pad rows (already zeroed by prefill assembly) quantize to
     zero pages, keeping recycled-pool contents deterministic.
     """
@@ -239,7 +239,7 @@ def entries_to_pages(leaf: jax.Array, page_size: int, storage: str,
                          f"page size {page_size}")
     paged = leaf.reshape((n, T // page_size, page_size) + leaf.shape[3:])
     if storage == "fp8":
-        q, s = quantize_vecs(paged, vec_ndim)
+        q, s = quantize_vecs(paged)
         return {"q": q, "scale": s}
     return {"q": paged.astype(store_dtype)}
 

@@ -20,7 +20,8 @@ call from one policy:
 
   1. ``with kernels.use_backend("ref"):``   thread-local override
   2. ``REPRO_KERNEL_BACKEND`` env var       process-level default
-  3. platform auto-detect                   TPU -> pallas, else interpret
+  3. platform auto-detect                   TPU -> pallas, CPU -> interpret
+                                            (any other platform raises)
 
 The selection is threaded into each backend's ``jax.jit`` boundary as a
 static argument, and ``use_backend`` drops jit caches when the backend

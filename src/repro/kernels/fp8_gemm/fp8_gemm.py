@@ -14,6 +14,11 @@ the "increased accumulation precision" the paper requests, natively.
 Grid: (M/bm, N/bn, K/128), K innermost for sequential accumulation.
 Default tiles bm=256, bn=256: VMEM ≈ bm*bk + bk*bn (bf16) + bm*bn*4 (acc)
 ≈ 0.4 MB — far under the ~16 MB/core budget, MXU-aligned (128 multiples).
+
+TPU tiling: the scale sidebands are re-laid out before the call so each
+block's last two dims are whole or (8, 128)-aligned — activation scales
+as a ``(K/128, M, 1)`` column per K-group, weight scales broadcast to a
+``(K/128, 1, N)`` lane row per K-group.
 """
 from __future__ import annotations
 
@@ -22,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 128  # scale granularity (fixed by the format)
 
@@ -35,10 +41,7 @@ def _kernel(xq_ref, xs_ref, wq_ref, ws_ref, o_ref, acc_ref):
     b = wq_ref[...].astype(jnp.bfloat16)          # (128, bn)
     part = jnp.dot(a, b, preferred_element_type=jnp.float32)
     # scales constant within this K-group: apply to the partial result
-    xs = xs_ref[...]                              # (bm, 1)
-    ws = ws_ref[...]                              # (1, bn/128)
-    scale = xs * jnp.repeat(ws, BLOCK, axis=1)    # (bm, bn)
-    acc_ref[...] += part * scale
+    acc_ref[...] += part * (xs_ref[0] * ws_ref[0])    # (bm,1)*(1,bn)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _emit():
@@ -53,7 +56,8 @@ def fp8_gemm(xq: jax.Array, xs: jax.Array, wq: jax.Array, ws: jax.Array,
     _, N = wq.shape
     assert K % BLOCK == 0 and M % bm == 0 and N % bn == 0, (M, K, N)
     assert xs.shape == (M, K // BLOCK) and ws.shape == (K // BLOCK, N // BLOCK)
-    from jax.experimental.pallas import tpu as pltpu
+    xs_col = xs.astype(jnp.float32).T[:, :, None]               # (kb, M, 1)
+    ws_row = jnp.repeat(ws.astype(jnp.float32), BLOCK, axis=1)[:, None, :]
 
     grid = (M // bm, N // bn, K // BLOCK)
     return pl.pallas_call(
@@ -61,12 +65,12 @@ def fp8_gemm(xq: jax.Array, xs: jax.Array, wq: jax.Array, ws: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, BLOCK), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, k)),
+            pl.BlockSpec((1, bm, 1), lambda i, j, k: (k, i, 0)),
             pl.BlockSpec((BLOCK, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn // BLOCK), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, k: (k, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(xq, xs, wq, ws)
+    )(xq, xs_col, wq, ws_row)

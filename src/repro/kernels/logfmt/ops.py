@@ -6,8 +6,9 @@ through ``repro.kernels.registry``: ``pallas``/``interpret`` run the VPU
 codec kernels with block shapes from the shape-bucketed table below;
 ``ref`` is the pure-jnp codec from ``repro.core.logfmt``. The feature dim
 must be a multiple of the 128-lane tile (fundamental to the wire format —
-pad upstream); both dims are padded to the block grid here and sliced
-back (padded tiles encode/decode zeros, so the sideband stays exact).
+pad upstream); rows are padded to the block grid here and sliced back
+(padded rows encode/decode zeros, so the sideband stays exact). A block
+is ``bn`` whole feature rows.
 """
 from __future__ import annotations
 
@@ -20,12 +21,10 @@ from repro.kernels import registry
 from repro.kernels.logfmt.logfmt import TILE, logfmt_decode, logfmt_encode
 from repro.kernels.logfmt.ref import logfmt_decode_ref, logfmt_encode_ref
 
-# bn buckets by row count; bd by feature dim (always a TILE multiple)
+# rows per block, bucketed by row count: 32 rows is the 8-bit code tile
+# height on TPU; one (32, 7168) fp32 block is ~0.9 MB of VMEM
 BLOCKS = registry.BlockTable({
-    1: dict(bn=8, bd=128),
-    64: dict(bn=64, bd=128),
-    128: dict(bn=128, bd=128),
-    512: dict(bn=128, bd=512),
+    1: dict(bn=32),
 })
 
 encode = registry.kernel("logfmt_encode", blocks=BLOCKS)
@@ -36,10 +35,6 @@ def _as2d(x: jax.Array) -> jax.Array:
     assert x.shape[-1] % TILE == 0, (
         f"LogFMT feature dim must be a multiple of {TILE}, got {x.shape}")
     return x.reshape(-1, x.shape[-1])
-
-
-def _blocks(n: int, d: int):
-    return BLOCKS.block(n, "bn"), BLOCKS.block(d, "bd")
 
 
 @encode.backend("ref")
@@ -56,13 +51,12 @@ def _encode_ref(x: jax.Array, *, n_bits: int = 8):
 def _encode_kernel(x: jax.Array, *, n_bits: int = 8, interpret: bool):
     shape = x.shape
     x2 = _as2d(x)
-    N, D = x2.shape
-    bn, bd = _blocks(N, D)
-    x2 = registry.pad_to_multiple(registry.pad_to_multiple(x2, 0, bn), 1, bd)
-    codes, mn, step = logfmt_encode(x2, n_bits=n_bits, bn=bn, bd=bd,
+    N = x2.shape[0]
+    bn = BLOCKS.block(N, "bn")
+    codes, mn, step = logfmt_encode(registry.pad_to_multiple(x2, 0, bn),
+                                    n_bits=n_bits, bn=bn,
                                     interpret=interpret)
-    codes, mn, step = (codes[:N, :D], mn[:N, :D // TILE],
-                       step[:N, :D // TILE])
+    codes, mn, step = codes[:N], mn[:N], step[:N]
     return (codes.reshape(shape), mn.reshape(shape[:-1] + (-1,)),
             step.reshape(shape[:-1] + (-1,)))
 
@@ -85,15 +79,10 @@ def _decode_kernel(codes: jax.Array, mn: jax.Array, step: jax.Array, *,
                    n_bits: int = 8, dtype=jnp.bfloat16, interpret: bool):
     shape = codes.shape
     c2 = _as2d(codes)
-    N, D = c2.shape
-    bn, bd = _blocks(N, D)
-    c2 = registry.pad_to_multiple(registry.pad_to_multiple(c2, 0, bn), 1, bd)
-    mn2 = registry.pad_to_multiple(
-        registry.pad_to_multiple(mn.reshape(-1, mn.shape[-1]), 0, bn),
-        1, bd // TILE)
-    step2 = registry.pad_to_multiple(
-        registry.pad_to_multiple(step.reshape(-1, step.shape[-1]), 0, bn),
-        1, bd // TILE)
-    y = logfmt_decode(c2, mn2, step2, n_bits=n_bits, bn=bn, bd=bd,
+    N = c2.shape[0]
+    bn = BLOCKS.block(N, "bn")
+    pad = registry.pad_to_multiple
+    y = logfmt_decode(pad(c2, 0, bn), pad(mn.reshape(N, -1), 0, bn),
+                      pad(step.reshape(N, -1), 0, bn), n_bits=n_bits, bn=bn,
                       dtype=dtype, interpret=interpret)
-    return y[:N, :D].reshape(shape)
+    return y[:N].reshape(shape)

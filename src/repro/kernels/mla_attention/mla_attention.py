@@ -19,6 +19,9 @@ by the caller).
 Block shapes: (H, R) = (128, 512) query tile is MXU-aligned; bt=256 cache
 rows/step => VMEM ≈ bt*(R+Rr)*4B ≈ 0.6 MB plus (H,bt) scores — well within
 budget while the arithmetic stays (H x bt x R) matmuls (MXU-friendly).
+TPU tiling: ``pos`` is viewed as (B, 1, T) so its block is a lane row
+(``bt`` a multiple of 128 or the whole cache), and ``qpos`` rides in SMEM
+as a scalar-prefetch operand.
 """
 from __future__ import annotations
 
@@ -27,12 +30,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-NEG = -1e30
+from repro.kernels.paged_attention.paged_attention import (NEG, dot_nn,
+                                                           dot_nt)
 
 
-def _kernel(qa_ref, qr_ref, ckv_ref, kr_ref, pos_ref, qpos_ref, o_ref,
+def _kernel(qpos_ref, qa_ref, qr_ref, ckv_ref, kr_ref, pos_ref, o_ref,
             m_ref, l_ref, acc_ref, *, scale: float):
+    b = pl.program_id(0)
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -41,25 +47,19 @@ def _kernel(qa_ref, qr_ref, ckv_ref, kr_ref, pos_ref, qpos_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qa = qa_ref[0]                                 # (H, R)
-    qr = qr_ref[0]                                 # (H, Rr)
     ckv = ckv_ref[0].astype(jnp.float32)           # (bt, R)
     kr = kr_ref[0].astype(jnp.float32)             # (bt, Rr)
-    pos = pos_ref[0]                               # (bt,)
-    qpos = qpos_ref[0]                             # scalar
-
-    s = (jnp.dot(qa, ckv.T, preferred_element_type=jnp.float32)
-         + jnp.dot(qr, kr.T, preferred_element_type=jnp.float32)) * scale
-    valid = (pos >= 0) & (pos <= qpos)             # (bt,)
-    s = jnp.where(valid[None, :], s, NEG)
+    pos = pos_ref[0]                               # (1, bt)
+    s = (dot_nt(qa_ref[0], ckv) + dot_nt(qr_ref[0], kr)) * scale
+    valid = (pos >= 0) & (pos <= qpos_ref[b])      # (1, bt)
+    s = jnp.where(valid, s, NEG)
 
     m_prev = m_ref[...]                            # (H, 1)
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid[None, :], jnp.exp(s - m_new), 0.0)  # (H, bt)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # (H, bt)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, ckv, preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + dot_nn(p, ckv)
     m_ref[...] = m_new
 
     @pl.when(t == pl.num_programs(1) - 1)
@@ -77,27 +77,29 @@ def mla_decode_kernel(q_abs: jax.Array, q_rope: jax.Array, ckv: jax.Array,
     Rr = q_rope.shape[-1]
     T = ckv.shape[1]
     assert T % bt == 0, (T, bt)
-    from jax.experimental.pallas import tpu as pltpu
 
-    grid = (B, T // bt)
-    return pl.pallas_call(
-        functools.partial(_kernel, scale=scale),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                     # qpos
+        grid=(B, T // bt),
         in_specs=[
-            pl.BlockSpec((1, H, R), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, H, Rr), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, bt, R), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, bt, Rr), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, bt), lambda b, t: (b, t)),
-            pl.BlockSpec((1,), lambda b, t: (b,)),
+            pl.BlockSpec((1, H, R), lambda b, t, qp: (b, 0, 0)),
+            pl.BlockSpec((1, H, Rr), lambda b, t, qp: (b, 0, 0)),
+            pl.BlockSpec((1, bt, R), lambda b, t, qp: (b, t, 0)),
+            pl.BlockSpec((1, bt, Rr), lambda b, t, qp: (b, t, 0)),
+            pl.BlockSpec((1, 1, bt), lambda b, t, qp: (b, 0, t)),
         ],
-        out_specs=pl.BlockSpec((1, H, R), lambda b, t: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
+        out_specs=pl.BlockSpec((1, H, R), lambda b, t, qp: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, R), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
         interpret=interpret,
-    )(q_abs.astype(jnp.float32), q_rope.astype(jnp.float32), ckv, kr,
-      pos, qpos)
+    )(qpos.astype(jnp.int32), q_abs.astype(jnp.float32),
+      q_rope.astype(jnp.float32), ckv, kr,
+      pos.astype(jnp.int32).reshape(B, 1, T))

@@ -16,12 +16,11 @@ from repro.kernels import registry
 from repro.kernels.mla_attention.mla_attention import mla_decode_kernel
 from repro.kernels.mla_attention.ref import mla_decode_ref
 
-# cache rows streamed per grid step: short caches take small blocks (less
-# padding), long caches take 256 rows (~0.6 MB VMEM per step, see
-# mla_attention.py)
+# cache rows streamed per grid step: caches under 128 rows take the whole
+# cache in one block, longer ones 128/256 rows (~0.6 MB VMEM per step, see
+# mla_attention.py) — the ``pos`` lane row needs bt % 128 == 0 or bt == T
 BLOCKS = registry.BlockTable({
-    1: dict(bt=32),
-    128: dict(bt=128),
+    1: dict(bt=128),
     512: dict(bt=256),
 })
 

@@ -22,9 +22,14 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = x_ref[0].astype(jnp.float32)
-    b = w_ref[0].astype(jnp.float32)
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    # operands in their own dtype: bf16 feeds the MXU natively with exact
+    # products; fp32 asks for full operand precision (Mosaic accepts the
+    # precision attribute on fp32 operands only)
+    a, b = x_ref[0], w_ref[0]
+    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    acc_ref[...] += jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), precision=prec,
+        preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _emit():
@@ -36,6 +41,7 @@ def moe_gemm(x: jax.Array, w: jax.Array, *, bc: int = 128, bf: int = 256,
              bk: int = 256, interpret: bool = False) -> jax.Array:
     E, C, D = x.shape
     _, _, F = w.shape
+    w = w.astype(x.dtype)
     bc, bf, bk = min(bc, C), min(bf, F), min(bk, D)
     assert C % bc == 0 and F % bf == 0 and D % bk == 0, (C, D, F)
     from jax.experimental.pallas import tpu as pltpu

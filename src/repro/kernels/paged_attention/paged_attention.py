@@ -1,4 +1,4 @@
-"""Paged MLA absorbed-decode flash kernel (paper §2.1.2 + §2.3.2).
+"""Paged MLA / GQA absorbed-decode flash kernels (paper §2.1.2 + §2.3.2).
 
 The dense flash-decode kernel (kernels/mla_attention) streams a slot's
 *contiguous* latent cache. Under the paged cache (core/paged.py) a slot's
@@ -9,12 +9,13 @@ table and dequantize in-register:
   grid = (B, pages_per_slot); step (b, t) DMAs physical page
   ``table[b, t]`` of the pool into VMEM via **scalar-prefetch indexing**
   (the page table is an SMEM-resident prefetch operand consumed by the
-  BlockSpec index maps), multiplies the E4M3 rows by their scales, and
-  folds the page into an online softmax over the latent dimension:
+  BlockSpec index maps), and folds the page into an online softmax over
+  the latent dimension. The per-token scales multiply the score columns
+  and the probability columns, never the (page, R) value tile, so the
+  scale sideband arrives as a lane row ``(1, page)``:
 
-    ckv = q8(page) * scale[page]                     (page, R)
-    s   = (q_abs @ ckv^T + q_rope @ kr^T) * scale    (H, page)
-    online-softmax accumulate  o = sum p * ckv       (H, R)
+    s   = (q_abs @ q8^T) * cs + (q_rope @ kr8^T) * ks   (H, page)
+    online-softmax accumulate  o = sum (p * cs) @ q8   (H, R)
 
 Validity is positional: logical row ``t*page + i`` of slot ``b`` is
 attendable iff it is ``<= qpos[b]`` (paged caches never ring-wrap, so
@@ -25,15 +26,11 @@ HBM traffic is one pass over the slot's *resident* pages at 1 byte/elem
 (+4/token scales) — the memory-bound decode path the paper's Table 1 /
 §2.3.2 roofline is about, at roughly half the bf16 bytes.
 
-Inputs:
-  table (B, pp) i32  physical page ids   [scalar prefetch]
-  qpos (B,) i32      current decode position per slot  [scalar prefetch]
-  q_abs (B, H, R) f32, q_rope (B, H, Rr) f32
-  ckv (P+1, page, R), kr (P+1, page, Rr)   fp8 (or native dtype)
-  ckv_s (P+1, page) f32, kr_s (P+1, page) f32  (ones for native storage)
-
-Output: o_lat (B, H, R) f32 — latent-space attention output (W_uv applied
-by the caller).
+TPU tiling: every block's last two dims are either whole array dims or
+(8, 128)-aligned. Pools are ``(P+1, page, width)`` so a page block
+``(1, page, width)`` always spans whole dims; scale sidebands are viewed
+as ``(P+1, 1, page)``. Mosaic refuses ``uint8 -> f32`` converts in-kernel,
+so byte pools are bitcast to E4M3 before the call.
 """
 from __future__ import annotations
 
@@ -42,8 +39,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def dot_nt(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b.T`` with fp32 accumulation at full fp32 operand precision
+    (the attention kernels' score product)."""
+    return _dot(a, b, (((1,), (1,)), ((), ())))
+
+
+def dot_nn(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` with fp32 accumulation at full fp32 operand precision."""
+    return _dot(a, b, (((1,), (0,)), ((), ())))
+
+
+def _online_softmax_step(s, valid, v_scaled_p, m_ref, l_ref, acc_ref, v):
+    """Fold one (rows, page) score tile into the running softmax state.
+    ``v_scaled_p`` maps probabilities to the value-side weights (per-token
+    dequant scale folded onto the probability columns)."""
+    s = jnp.where(valid, s, NEG)
+    m_prev = m_ref[...]                                # (rows, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)      # (rows, page)
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + dot_nn(v_scaled_p(p), v)
+    m_ref[...] = m_new
 
 
 def _kernel(table_ref, qpos_ref, qa_ref, qr_ref, ckv_ref, kr_ref,
@@ -57,31 +86,25 @@ def _kernel(table_ref, qpos_ref, qa_ref, qr_ref, ckv_ref, kr_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qa = qa_ref[0]                                     # (H, R)
-    qr = qr_ref[0]                                     # (H, Rr)
-    # in-register dequantization: one fp32 scale per token row
-    ckv = ckv_ref[0].astype(jnp.float32) * cs_ref[0][:, None]   # (page, R)
-    kr = kr_ref[0].astype(jnp.float32) * ks_ref[0][:, None]     # (page, Rr)
-
-    s = jnp.dot(qa, ckv.T, preferred_element_type=jnp.float32) \
-        + jnp.dot(qr, kr.T, preferred_element_type=jnp.float32)
+    ckv = ckv_ref[0].astype(jnp.float32)               # (page, R)
+    kr = kr_ref[0].astype(jnp.float32)                 # (page, Rr)
+    cs = cs_ref[0]                                     # (1, page)
+    s = (dot_nt(qa_ref[0], ckv) * cs
+         + dot_nt(qr_ref[0], kr) * ks_ref[0])       # (H, page)
     # positional validity: logical row index vs current decode position
     lpos = t * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     valid = lpos <= qpos_ref[b]                        # (1, page)
-    s = jnp.where(valid, s, NEG)
-
-    m_prev = m_ref[...]                                # (H, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)      # (H, page)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, ckv, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    _online_softmax_step(s, valid, lambda p: p * cs, m_ref, l_ref, acc_ref,
+                         ckv)
 
     @pl.when(t == pl.num_programs(1) - 1)
     def _emit():
         o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _row_scales(s: jax.Array) -> jax.Array:
+    """(P+1, page) per-token scales -> (P+1, 1, page) lane rows."""
+    return s.reshape(s.shape[0], 1, s.shape[1]).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -91,11 +114,14 @@ def paged_mla_decode_kernel(q_abs: jax.Array, q_rope: jax.Array,
                             table: jax.Array, qpos: jax.Array, *,
                             scale: float,
                             interpret: bool = False) -> jax.Array:
+    """q_abs (B,H,R) / q_rope (B,H,Rr); ckv (P+1, page, R) and kr (P+1,
+    page, Rr) in E4M3 or the native dtype; ckv_s/kr_s (P+1, page) fp32
+    per-token scales (ones for native storage); table (B, pp) physical
+    page ids; qpos (B,). Returns the latent output (B, H, R) fp32."""
     B, H, R = q_abs.shape
     Rr = q_rope.shape[-1]
     page = ckv.shape[1]
     pp = table.shape[1]
-    from jax.experimental.pallas import tpu as pltpu
 
     # scale folded into q (fp8 rows are scaled per token, so the score
     # scale distributes onto the query side for free)
@@ -110,8 +136,8 @@ def paged_mla_decode_kernel(q_abs: jax.Array, q_rope: jax.Array,
             pl.BlockSpec((1, H, Rr), lambda b, t, tbl, qp: (b, 0, 0)),
             pl.BlockSpec((1, page, R), lambda b, t, tbl, qp: (tbl[b, t], 0, 0)),
             pl.BlockSpec((1, page, Rr), lambda b, t, tbl, qp: (tbl[b, t], 0, 0)),
-            pl.BlockSpec((1, page), lambda b, t, tbl, qp: (tbl[b, t], 0)),
-            pl.BlockSpec((1, page), lambda b, t, tbl, qp: (tbl[b, t], 0)),
+            pl.BlockSpec((1, 1, page), lambda b, t, tbl, qp: (tbl[b, t], 0, 0)),
+            pl.BlockSpec((1, 1, page), lambda b, t, tbl, qp: (tbl[b, t], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, H, R), lambda b, t, tbl, qp: (b, 0, 0)),
         scratch_shapes=[
@@ -125,7 +151,7 @@ def paged_mla_decode_kernel(q_abs: jax.Array, q_rope: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
         interpret=interpret,
-    )(table, qpos, qa, qr, ckv, kr, ckv_s, kr_s)
+    )(table, qpos, qa, qr, ckv, kr, _row_scales(ckv_s), _row_scales(kr_s))
 
 
 def _gqa_kernel(table_ref, qpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
@@ -142,25 +168,15 @@ def _gqa_kernel(table_ref, qpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                                    # (G, hd) pre-scaled
-    # in-register dequantization: one fp32 scale per token row
-    k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0][:, None]  # (page, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0][:, None]
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)      # (G, page)
+    k = k_ref[0].astype(jnp.float32)                   # (page, hd)
+    v = v_ref[0].astype(jnp.float32)
+    vs = vs_ref[0]                                     # (1, page)
+    s = dot_nt(q_ref[0, 0], k) * ks_ref[0]          # (G, page)
     # positional validity: logical row index vs current decode position
     lpos = t * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     valid = lpos <= qpos_ref[b]                        # (1, page)
-    s = jnp.where(valid, s, NEG)
-
-    m_prev = m_ref[...]                                # (G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)      # (G, page)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    _online_softmax_step(s, valid, lambda p: p * vs, m_ref, l_ref, acc_ref,
+                         v)
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _emit():
@@ -177,15 +193,16 @@ def paged_gqa_decode_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
     with the head axis split (KV, G) so each grid step streams one KV
     head's page while its G query heads accumulate online-softmax state.
 
-    q (B, H, hd) f32; k/v (P+1, page, KV, hd) E4M3 or native; k_s/v_s
-    (P+1, page) f32 per-token scales (ones for native storage); table
-    (B, pp) physical page ids; qpos (B,). Returns (B, H, hd) f32.
+    q (B, H, hd); k/v (P+1, page, KV*hd) E4M3 or native — one token's
+    K (or V) entry is a flat row, KV head ``j`` at lanes ``[j*hd,
+    (j+1)*hd)``; k_s/v_s (P+1, page) fp32 per-token scales (ones for
+    native storage); table (B, pp) physical page ids; qpos (B,). On TPU
+    ``hd`` must be a multiple of 128 (or KV == 1). Returns (B, H, hd) fp32.
     """
     B, H, hd = q.shape
-    page, KV = k.shape[1], k.shape[2]
+    page, KV = k.shape[1], k.shape[2] // hd
     G = H // KV
     pp = table.shape[1]
-    from jax.experimental.pallas import tpu as pltpu
 
     # scale folded into q (fp8 rows carry per-token scales, so the score
     # scale distributes onto the query side for free); head axis factors
@@ -198,14 +215,14 @@ def paged_gqa_decode_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, G, hd),
                          lambda b, kv, t, tbl, qp: (b, kv, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda b, kv, t, tbl, qp: (tbl[b, t], 0, kv, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda b, kv, t, tbl, qp: (tbl[b, t], 0, kv, 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, kv, t, tbl, qp: (tbl[b, t], 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, kv, t, tbl, qp: (tbl[b, t], 0)),
+            pl.BlockSpec((1, page, hd),
+                         lambda b, kv, t, tbl, qp: (tbl[b, t], 0, kv)),
+            pl.BlockSpec((1, page, hd),
+                         lambda b, kv, t, tbl, qp: (tbl[b, t], 0, kv)),
+            pl.BlockSpec((1, 1, page),
+                         lambda b, kv, t, tbl, qp: (tbl[b, t], 0, 0)),
+            pl.BlockSpec((1, 1, page),
+                         lambda b, kv, t, tbl, qp: (tbl[b, t], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, kv, t, tbl, qp: (b, kv, 0, 0)),
@@ -220,5 +237,5 @@ def paged_gqa_decode_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), jnp.float32),
         interpret=interpret,
-    )(table, qpos, qf, k, v, k_s, v_s)
+    )(table, qpos, qf, k, v, _row_scales(k_s), _row_scales(v_s))
     return out.reshape(B, H, hd)

@@ -32,17 +32,18 @@ def paged_mla_decode_ref(q_abs, q_rope, ckv, kr, ckv_s, kr_s, table,
 def paged_gqa_decode_ref(q, k, v, k_s, v_s, table, qpos, *, scale: float):
     """Gather + full softmax reference for the paged GQA decode kernel.
 
-    q (B,H,hd) fp32; k/v (P+1, page, KV, hd) in the storage dtype with
-    per-token scales k_s/v_s (P+1, page); table (B, pp) physical page
-    ids; qpos (B,). The head axis factors as (KV, G) — one KV head per
-    group of G = H // KV query heads. Returns (B, H, hd) fp32.
+    q (B,H,hd) fp32; k/v (P+1, page, KV*hd) in the storage dtype (one
+    token's entry is a flat row of KV heads) with per-token scales
+    k_s/v_s (P+1, page); table (B, pp) physical page ids; qpos (B,). The
+    head axis factors as (KV, G) — one KV head per group of G = H // KV
+    query heads. Returns (B, H, hd) fp32.
     """
     B, H, hd = q.shape
-    page, KV = k.shape[1], k.shape[2]
+    page, KV = k.shape[1], k.shape[2] // hd
     G = H // KV
     pp = table.shape[1]
-    kf = k.astype(jnp.float32) * k_s[..., None, None]
-    vf = v.astype(jnp.float32) * v_s[..., None, None]
+    kf = k.astype(jnp.float32) * k_s[..., None]
+    vf = v.astype(jnp.float32) * v_s[..., None]
     kt = kf[table].reshape(B, pp * page, KV, hd)        # (B, T, KV, hd)
     vt = vf[table].reshape(B, pp * page, KV, hd)
     qg = q.astype(jnp.float32).reshape(B, KV, G, hd)

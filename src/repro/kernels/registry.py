@@ -14,7 +14,8 @@ resolves the backend from a single policy, in priority order:
 
   1. ``with kernels.use_backend("ref"):`` — thread-local override
   2. ``REPRO_KERNEL_BACKEND=pallas|interpret|ref`` environment variable
-  3. platform auto-detect: TPU -> ``pallas``, anything else -> ``interpret``
+  3. platform auto-detect: TPU -> ``pallas``, CPU -> ``interpret``; any
+     other platform raises rather than run the interpreter unnoticed
 
 This replaces the old per-subpackage ``ops.py`` convention where every
 wrapper grew its own ``use_ref``/``interpret`` kwargs and an
@@ -150,7 +151,15 @@ def _validate_backend(name: str) -> str:
 
 @functools.lru_cache(maxsize=None)
 def _platform_default() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "interpret"
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return "pallas"
+    if platform == "cpu":
+        return "interpret"
+    raise RuntimeError(
+        f"no default kernel backend on platform {platform!r}: the Pallas "
+        "kernels compile for TPU and run through the interpreter on CPU "
+        f"only; choose one with {ENV_VAR} or kernels.use_backend(...)")
 
 
 def active_backend() -> str:

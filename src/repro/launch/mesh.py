@@ -6,14 +6,19 @@ axis is the scarce DCN/optical fabric (the paper's IB analogue); EP
 all-to-all is confined to intra-pod axes by construction (DESIGN.md §5).
 
 Defined as functions so importing this module never touches jax device
-state (device count is locked at first backend init). Mesh creation goes
-through ``repro.compat`` (``axis_types`` only exists on newer jax).
+state (device count is locked at first backend init). Meshes use
+``Auto`` axes: GSPMD propagates the shardings the rules declare.
 """
 from __future__ import annotations
 
-from jax.sharding import Mesh
+import jax
+from jax.sharding import AxisType, Mesh
 
-from repro.compat import make_mesh
+
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` over ``Auto`` axes (its default is Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -47,7 +47,7 @@ def _make_ctx(spec, moe_impl: str, wire: str):
     single-device default)."""
     if not spec:
         return None
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel import context as pctx_mod
     try:
         shape = tuple(int(s) for s in spec.replace("x", ",").split(","))
@@ -118,6 +118,8 @@ def main():
             raise SystemExit("--host-tier-pages does not apply to the "
                              "--disagg decode pool yet")
         paged_kw["host_tier_pages"] = args.host_tier_pages
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ctx = _make_ctx(args.mesh, args.moe_impl, args.wire)
     if args.prefill_mesh and not args.disagg:
         raise SystemExit("--prefill-mesh only applies with --disagg")

@@ -43,10 +43,12 @@ def main():
     import jax
 
     from repro.configs.base import get_config, smoke_config
+    from repro.launch import compile_cache
     from repro.launch.mesh import make_mesh
     from repro.parallel import context as pctx_mod
     from repro.train.trainer import Trainer, TrainConfig
 
+    compile_cache.enable()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)

@@ -49,29 +49,6 @@ from repro.models.param import (ParamSpec, count, init_params, param_structs,
                                 spec_axes)
 
 
-@jax.custom_vjp
-def _diff_barrier(tree):
-    """``optimization_barrier`` with a VJP (jax 0.4.37 has no built-in
-    differentiation rule for it): barrier the cotangents too, so the
-    backward scan keeps the same no-hoisting property as the forward."""
-    return jax.lax.optimization_barrier(tree)
-
-
-def _diff_barrier_fwd(tree):
-    return _diff_barrier(tree), None
-
-
-def _diff_barrier_bwd(_, g):
-    def barrier(leaf):
-        if leaf.dtype == jax.dtypes.float0:   # non-differentiable leaf
-            return leaf
-        return jax.lax.optimization_barrier(leaf)
-    return (jax.tree.map(barrier, g),)
-
-
-_diff_barrier.defvjp(_diff_barrier_fwd, _diff_barrier_bwd)
-
-
 def apply_remat(step, policy: str):
     """Wrap a scan step with the ctx's remat policy (none | full | dots).
     Single source for the single-batch backbone and the dual-microbatch
@@ -123,8 +100,11 @@ def _segments(cfg: ModelConfig) -> List[Segment]:
             return [Segment("blocks", "moe", L)]
         if lay.startswith("dense_first:"):
             n0 = int(lay.split(":")[1])
-            return [Segment("dense0", "dense", n0),
-                    Segment("blocks", "moe", L - n0)]
+            # a depth cut to the leading dense layers has no MoE segment
+            segs = [Segment("dense0", "dense", min(n0, L))]
+            if L > n0:
+                segs.append(Segment("blocks", "moe", L - n0))
+            return segs
         if lay.startswith("interleave:"):
             k = int(lay.split(":")[1])
             assert k == 2 and L % 2 == 0, (lay, L)
@@ -360,8 +340,16 @@ class Model:
                     cfg, (n,), d_ff=cfg.d_ff))
         return s
 
-    def init(self, rng: jax.Array):
-        return init_params(self.specs(), rng)
+    def init(self, rng: jax.Array, shardings=None):
+        """Random parameters from ``rng``, drawn on the device in one jit.
+
+        ``shardings`` (a pytree of ``NamedSharding`` matching the params)
+        makes each device draw only its own shard, so a model larger than
+        one chip never exists whole anywhere. Threefry is partitionable in
+        the installed JAX, so a sharded draw gives the same bits as an
+        unsharded one."""
+        return jax.jit(functools.partial(init_params, self.specs()),
+                       out_shardings=shardings)(rng)
 
     def param_structs(self):
         return param_structs(self.specs())
@@ -406,9 +394,11 @@ class Model:
             # barrier the per-layer slices: stops XLA from hoisting dtype
             # converts of sliced operands out of the loop, which would
             # materialize f32 copies of entire (L, ...) weight/cache stacks
-            ps = _diff_barrier(ps)
+            # (the barrier's transpose rule barriers the cotangents too, so
+            # the backward scan keeps the same property)
+            ps = jax.lax.optimization_barrier(ps)
             if cs is not None:
-                cs = _diff_barrier(cs)
+                cs = jax.lax.optimization_barrier(cs)
             h, nc, st = _apply_kind(seg, ps, h, cfg, ctx, cs)
             return shard_act(h), (nc, st)
 
@@ -1140,9 +1130,11 @@ class Model:
             for name in ("ckv", "kr", "k", "v"):
                 if name not in sub:
                     continue
-                vnd = 2 if name in ("k", "v") else 1
-                d = paged_mod.entries_to_pages(sub[name], page_size,
-                                               storage, store, vnd)
+                leaf = sub[name]
+                # GQA entries (n, 1, T, KV, hd) page as flat rows
+                leaf = leaf.reshape(leaf.shape[:3] + (-1,))
+                d = paged_mod.entries_to_pages(leaf, page_size, storage,
+                                               store)
                 out[name] = d["q"]
                 if "scale" in d:
                     out[name + "_scale"] = d["scale"]

@@ -221,7 +221,8 @@ def gqa_attention(p: dict, x: jax.Array, *, cfg: ModelConfig,
         # the fused-decode step; S > 1 is a page-aligned chunked-prefill
         # run written whole-pages-first (page_write_chunk).
         from repro.core import paged
-        S = x.shape[1]
+        B, S = x.shape[:2]
+        KV = cfg.num_kv_heads
         qpos = positions[:, 0]
         fp8 = "k_scale" in cache
         new_cache = dict(cache)
@@ -231,16 +232,18 @@ def gqa_attention(p: dict, x: jax.Array, *, cfg: ModelConfig,
         else:
             def pwrite(pool, vals):
                 return paged.page_write_chunk(pool, page_table, qpos, vals)
+        # pools hold one token's (KV, hd) entry as a flat row
+        kf, vf = k.reshape(B, S, KV * hd), v.reshape(B, S, KV * hd)
         if fp8:
-            qk, sk = paged.quantize_vecs(k, vec_ndim=2)
-            qv, sv = paged.quantize_vecs(v, vec_ndim=2)
+            qk, sk = paged.quantize_vecs(kf)
+            qv, sv = paged.quantize_vecs(vf)
             new_cache["k"] = pwrite(cache["k"], qk)
             new_cache["v"] = pwrite(cache["v"], qv)
             new_cache["k_scale"] = pwrite(cache["k_scale"], sk)
             new_cache["v_scale"] = pwrite(cache["v_scale"], sv)
         else:
-            new_cache["k"] = pwrite(cache["k"], k)
-            new_cache["v"] = pwrite(cache["v"], v)
+            new_cache["k"] = pwrite(cache["k"], kf)
+            new_cache["v"] = pwrite(cache["v"], vf)
         if impl == "pallas" and S == 1 and not window:
             # registry-dispatched scalar-prefetch kernel: walks the page
             # table in SMEM, dequantizes E4M3 rows in-register, online
@@ -261,20 +264,22 @@ def gqa_attention(p: dict, x: jax.Array, *, cfg: ModelConfig,
         else:
             if fp8:
                 kc = paged.gather_dequant(new_cache["k"], new_cache["k_scale"],
-                                          page_table, vec_ndim=2).astype(cfg.dtype)
+                                          page_table).astype(cfg.dtype)
                 vc = paged.gather_dequant(new_cache["v"], new_cache["v_scale"],
-                                          page_table, vec_ndim=2).astype(cfg.dtype)
+                                          page_table).astype(cfg.dtype)
             else:
                 kc = paged.table_gather(new_cache["k"], page_table)
                 vc = paged.table_gather(new_cache["v"], page_table)
                 kc = kc.astype(cfg.dtype) if kc.dtype != jnp.dtype(cfg.dtype) else kc
                 vc = vc.astype(cfg.dtype) if vc.dtype != jnp.dtype(cfg.dtype) else vc
+            T = kc.shape[1]
+            kc = kc.reshape(B, T, KV, hd)
+            vc = vc.reshape(B, T, KV, hd)
             # positional validity: k_pos is the logical index itself (pages
             # never ring-wrap), so attention_scores' mask k_pos <= q_pos is
             # exactly "written by this slot"; stale/trash rows sit above qpos
-            T = kc.shape[1]
             kpos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :],
-                                    (kc.shape[0], T))
+                                    (B, T))
             out = attention_scores(q, kc, vc, causal=causal,
                                    q_pos=positions, k_pos=kpos,
                                    window=window, impl=impl)
@@ -320,10 +325,12 @@ def init_paged_gqa_cache(cfg: ModelConfig, layers: int, pool_pages: int,
                          page_size: int, storage: str) -> dict:
     """K/V page pool (no batch axis: pages are shared across slots).
 
-    Leaves ``(layers, pool_pages+1, page, KV, hd)``; the last page is the
-    trash page. FP8 storage adds per-token fp32 scale leaves (one scale
-    over a token's whole ``(KV, hd)`` entry). No ``pos`` leaf — validity
-    is positional (see ``core/paged.py``).
+    Leaves ``(layers, pool_pages+1, page, KV*hd)`` — one token's ``(KV,
+    hd)`` entry is a flat row, so a page is a ``(page, KV*hd)`` tile whose
+    KV heads are 128-lane blocks for the paged kernel; the last page is
+    the trash page. FP8 storage adds per-token fp32 scale leaves (one
+    scale over a token's whole entry). No ``pos`` leaf — validity is
+    positional (see ``core/paged.py``).
     """
     from repro.core import paged
     paged.validate_storage(storage)
@@ -334,8 +341,8 @@ def init_paged_gqa_cache(cfg: ModelConfig, layers: int, pool_pages: int,
     dt = jnp.uint8 if fp8 else jnp.dtype(cfg.cache_dtype_())
     P1 = pool_pages + 1
     c = dict(
-        k=jnp.zeros((layers, P1, page_size, cfg.num_kv_heads, hd), dt),
-        v=jnp.zeros((layers, P1, page_size, cfg.num_kv_heads, hd), dt),
+        k=jnp.zeros((layers, P1, page_size, cfg.num_kv_heads * hd), dt),
+        v=jnp.zeros((layers, P1, page_size, cfg.num_kv_heads * hd), dt),
     )
     if fp8:
         c["k_scale"] = jnp.zeros((layers, P1, page_size), jnp.float32)

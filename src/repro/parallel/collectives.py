@@ -17,7 +17,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import logfmt
 
 
@@ -32,7 +31,7 @@ def compressed_psum(x: jax.Array, axis: str, n_bits: int = 8) -> jax.Array:
     d padded to the LogFMT tile internally. Returns the summed array
     (same on every member, like psum).
     """
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     shape = x.shape
@@ -131,7 +130,7 @@ def sharded_global_norm(tree, mesh, pspecs) -> jax.Array:
             s = jax.lax.psum(s, a)
         return s
 
-    sq = compat.shard_map(
+    sq = jax.shard_map(
         local, mesh=mesh, in_specs=(tuple(spec_leaves),), out_specs=P(),
         check_vma=False)(tuple(leaves))
     return jnp.sqrt(sq)

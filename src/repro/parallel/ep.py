@@ -48,8 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.core import fp8, moe as moe_mod, routing
 from repro.parallel.context import ParallelCtx
@@ -89,7 +87,7 @@ def _scatter_rows(n_slots: int, dest: jax.Array, keep: jax.Array,
 
 
 def _slice_tokens(x, mask, axis: str):
-    cols = compat.axis_size(axis)
+    cols = jax.lax.axis_size(axis)
     j = jax.lax.axis_index(axis)
     per = x.shape[0] // cols
     xt = jax.lax.dynamic_slice_in_dim(x, j * per, per, axis=0)
@@ -115,7 +113,7 @@ def _group_perm(cols: int, cpg: int, step: int):
 def _group_allgather(z: jax.Array, axis: str, cpg: int) -> jax.Array:
     """z: this column's hop-1 chunk (owner rank = col%cpg). Returns
     (cpg, *z.shape) with index r = the chunk owned by group-rank r."""
-    cols = compat.axis_size(axis)
+    cols = jax.lax.axis_size(axis)
     rj = jax.lax.axis_index(axis) % cpg
     received = [z]                                   # rank rj
     for step in range(1, cpg):
@@ -128,7 +126,7 @@ def _group_allgather(z: jax.Array, axis: str, cpg: int) -> jax.Array:
 def _group_reduce(parts: jax.Array, axis: str, cpg: int) -> jax.Array:
     """parts: (cpg, ...) this column's partial outputs indexed by owner
     rank. Returns this column's own chunk summed over the group."""
-    cols = compat.axis_size(axis)
+    cols = jax.lax.axis_size(axis)
     rj = jax.lax.axis_index(axis) % cpg
     acc = jnp.take(parts, rj, axis=0)
     for step in range(1, cpg):
@@ -145,7 +143,7 @@ def _group_reduce(parts: jax.Array, axis: str, cpg: int) -> jax.Array:
 def _ep_flat_local(wg, bias, w1, w3, w2, x, mask, cfg: ModelConfig,
                    axis: str, wire: str = "fp8"):
     mc = cfg.moe
-    cols = compat.axis_size(axis)
+    cols = jax.lax.axis_size(axis)
     E_l = mc.num_experts // cols
     xt, mt = _slice_tokens(x, mask, axis)
     t, d = xt.shape
@@ -206,7 +204,7 @@ def _ep_flat_local(wg, bias, w1, w3, w2, x, mask, cfg: ModelConfig,
 def _ep_dedup_local(wg, bias, w1, w3, w2, x, mask, cfg: ModelConfig,
                     axis: str, wire: str = "fp8"):
     mc = cfg.moe
-    cols = compat.axis_size(axis)
+    cols = jax.lax.axis_size(axis)
     G = mc.num_groups
     assert cols % G == 0, (cols, G)
     cpg = cols // G
@@ -381,7 +379,7 @@ def moe_ffn_sharded(p: dict, x: jax.Array, cfg: ModelConfig,
     if bias is None:
         bias = jnp.zeros((mc.num_experts,), jnp.float32)
     w2spec = P(axis, "data", None) if ftp else espec   # w2: (E, f, d)
-    y, load, drop, aux = shard_map(
+    y, load, drop, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, None), P(None), espec, espec, w2spec, xspec, mspec),
         out_specs=(xspec, P(None), P(), P()),

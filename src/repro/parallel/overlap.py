@@ -35,7 +35,7 @@ from typing import Callable, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.api import Model, _apply_kind, _diff_barrier, apply_remat
+from repro.models.api import Model, _apply_kind, apply_remat
 
 
 def dual_backbone(model: Model, params, tokensA, tokensB, ctxA, ctxB):
@@ -60,7 +60,7 @@ def dual_backbone(model: Model, params, tokensA, tokensB, ctxA, ctxB):
 
         def step(carry, ps):
             hA, hB = carry
-            ps = _diff_barrier(ps)
+            ps = jax.lax.optimization_barrier(ps)
             hA, _, stA = _apply_kind(seg, ps, hA, cfg, ctxA, None)
             hB, _, stB = _apply_kind(seg, ps, hB, cfg, ctxB, None)
             return (shard_act(hA), shard_act(hB)), (stA, stB)
@@ -169,11 +169,11 @@ def dual_decode_step(model: Model, params, cacheA, cacheB, tokA, tokB,
         def step(carry, xs):
             hA, hB = carry
             ps, csA, csB = xs
-            ps = _diff_barrier(ps)
+            ps = jax.lax.optimization_barrier(ps)
             if csA is not None:
-                csA = _diff_barrier(csA)
+                csA = jax.lax.optimization_barrier(csA)
             if csB is not None:
-                csB = _diff_barrier(csB)
+                csB = jax.lax.optimization_barrier(csB)
             hA, ncA, _ = _apply_kind(seg, ps, hA, cfg, ctxA, csA)
             hB, ncB, _ = _apply_kind(seg, ps, hB, cfg, ctxB, csB)
             return (shard_act(hA), shard_act(hB)), (ncA, ncB)

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def _shift(x: jax.Array, axis: str, n: int) -> jax.Array:
@@ -89,9 +88,9 @@ def pipeline_forward(stage_fn: Callable, params_stages, x_mb: jax.Array,
         return outputs
 
     pspec = jax.tree.map(lambda _: P(axis), params_stages)
-    return shard_map(local, mesh=mesh,
-                     in_specs=(pspec, P()), out_specs=P(),
-                     check_vma=False)(params_stages, x_mb)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(pspec, P()), out_specs=P(),
+                         check_vma=False)(params_stages, x_mb)
 
 
 # ---------------------------------------------------------------------------

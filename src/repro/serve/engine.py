@@ -197,8 +197,18 @@ class ServeEngine:
         self.attn_impl = attn_impl
         self.ctx = ctx
         self.meshed = ctx is not None and ctx.mesh is not None
+        self._param_shardings = None
+        if self.meshed:
+            from repro.parallel import sharding
+            rules = sharding.serve_rules(
+                "pod" in ctx.mesh.axis_names,
+                ep_ftp=getattr(ctx, "ep_ftp", False))
+            self._param_shardings = sharding.param_shardings(
+                ctx.mesh, self.model.specs(), rules)
+        # meshed engines draw each device's shard in place (sharded init)
         self.params = (params if params is not None
-                       else self.model.init(jax.random.PRNGKey(seed)))
+                       else self.model.init(jax.random.PRNGKey(seed),
+                                            self._param_shardings))
         self.slots = slots
         self.max_len = max_len
         self.use_mtp = use_mtp and cfg.mtp is not None
@@ -338,7 +348,6 @@ class ServeEngine:
         self._tier_gather_traces = 0
         self._tier_scatter_traces = 0
         self._tier_resume_traces = 0
-        donate = jax.default_backend() != "cpu"
         # meshed engines pin the cache/state out-shardings to the input
         # shardings: without the pin, GSPMD could hand back a re-sharded
         # cache and the next dispatch would see new input shardings and
@@ -357,7 +366,7 @@ class ServeEngine:
             # repro-lint: disable=R2-jit-contract -- donated; output is
             # the wire payload, not the pool cache
             self._quant_fn = jax.jit(
-                quant, donate_argnums=(0,) if donate else ())
+                quant, donate_argnums=(0,))
 
             def scatter(cache, pages, aux, ids, row, slot):
                 self._scatter_traces += 1
@@ -368,7 +377,7 @@ class ServeEngine:
                 return cache
 
             self._scatter_fn = jax.jit(
-                scatter, donate_argnums=(0,) if donate else (),
+                scatter, donate_argnums=(0,),
                 out_shardings=cache_out)
 
             def release(cache, slot):
@@ -376,7 +385,7 @@ class ServeEngine:
                 return self.model.release_slot_pages(cache, slot)
 
             self._release_fn = jax.jit(
-                release, donate_argnums=(0,) if donate else (),
+                release, donate_argnums=(0,),
                 out_shardings=cache_out)
 
             if self.prefill_chunk is not None:
@@ -389,7 +398,7 @@ class ServeEngine:
 
                 # logits are a fresh (1,1,V) payload; the cache carries
                 self._chunk_fn = jax.jit(
-                    chunk_prefill, donate_argnums=(1,) if donate else (),
+                    chunk_prefill, donate_argnums=(1,),
                     out_shardings=(None, cache_out) if self.meshed else None)
 
                 def table_install(cache, row, slot):
@@ -401,7 +410,7 @@ class ServeEngine:
                     return out
 
                 self._table_fn = jax.jit(
-                    table_install, donate_argnums=(0,) if donate else (),
+                    table_install, donate_argnums=(0,),
                     out_shardings=cache_out)
 
             if self.tier is not None:
@@ -446,7 +455,7 @@ class ServeEngine:
                     return self.model.install_pages(cache, pages, ids)
 
                 self._tier_scatter_fn = jax.jit(
-                    tier_scatter, donate_argnums=(0,) if donate else (),
+                    tier_scatter, donate_argnums=(0,),
                     out_shardings=cache_out)
 
                 def tier_resume(cache, aux, row, slot):
@@ -461,7 +470,7 @@ class ServeEngine:
                     return out
 
                 self._tier_resume_fn = jax.jit(
-                    tier_resume, donate_argnums=(0,) if donate else (),
+                    tier_resume, donate_argnums=(0,),
                     out_shardings=cache_out)
         else:
             axes = self.model.cache_batch_axes(slots, max_len)
@@ -471,7 +480,7 @@ class ServeEngine:
                 return _splice(big, small, slot, axes)
 
             self._splice_fn = jax.jit(
-                splice, donate_argnums=(0,) if donate else (),
+                splice, donate_argnums=(0,),
                 out_shardings=cache_out)
 
         def decode_chunk(params, cache, state):
@@ -487,7 +496,7 @@ class ServeEngine:
             decode_out = (self._tok_sharding, self._tok_sharding,
                           self._cache_shardings, self._state_shardings)
         self._decode_fn = jax.jit(
-            decode_chunk, donate_argnums=(1, 2) if donate else (),
+            decode_chunk, donate_argnums=(1, 2),
             out_shardings=decode_out)
 
     # -- mesh install --------------------------------------------------------
@@ -512,10 +521,8 @@ class ServeEngine:
         from repro.parallel import sharding
         ctx = self.ctx
         mesh = ctx.mesh
-        rules = sharding.serve_rules("pod" in mesh.axis_names,
-                                     ep_ftp=getattr(ctx, "ep_ftp", False))
-        self._param_shardings = sharding.param_shardings(
-            mesh, self.model.specs(), rules)
+        # caller-supplied params may live anywhere; engine-drawn ones are
+        # already in place and this is a no-op
         # repro-lint: disable=R1-host-sync -- one-time mesh install at
         # engine construction, not a decode-loop transfer
         self.params = jax.device_put(self.params, self._param_shardings)

@@ -25,8 +25,11 @@ def _is_float(x):
 
 
 def init(params) -> AdamWState:
+    # a fresh buffer even for fp32 params: the train step donates params
+    # and optimizer state together, and one buffer cannot be donated twice
     master = jax.tree.map(
-        lambda p: p.astype(jnp.float32) if _is_float(p) else p, params)
+        lambda p: jnp.array(p, jnp.float32, copy=True) if _is_float(p)
+        else p, params)
     m = jax.tree.map(
         lambda p: jnp.zeros(p.shape, jnp.bfloat16) if _is_float(p) else None,
         params)

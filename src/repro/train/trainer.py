@@ -246,31 +246,22 @@ class Trainer:
             self.opt_state = state["opt"]
             self.step = int(extras["step"])
         else:
-            self.params = self.model.init(rng)
-            self.opt_state = optim.init(self.params)
-            if self.meshed:
-                # init unsharded then shard: non-partitionable threefry
-                # (jax<=0.4 default) draws different bits under a
-                # partitioned lowering, which would break sharded-vs-
-                # single-device trajectory parity (and mesh-shape-
-                # independent restarts) from step 0
-                # repro-lint: disable=R1-host-sync -- one-time state
-                # sharding at init/restore, not the step loop
-                self.params = jax.device_put(self.params,
-                                             shardings["params"])
-                # repro-lint: disable=R1-host-sync -- one-time state
-                # sharding at init/restore, not the step loop
-                self.opt_state = jax.device_put(self.opt_state,
-                                                shardings["opt"])
+            # sharded trainers draw each device's shard in place
+            self.params = self.model.init(
+                rng, shardings["params"] if self.meshed else None)
+            # repro-lint: disable=R2-jit-contract -- the params are read,
+            # not consumed: the optimizer state is built beside them
+            self.opt_state = jax.jit(
+                optim.init,
+                out_shardings=shardings["opt"] if self.meshed else None)(
+                    self.params)
             self.step = 0
         # donate params + opt state so the update happens in place —
         # without it the fp32 master + bf16 m/v live twice per step,
-        # blowing the 10-byte/param budget. CPU XLA has no donation
-        # (would only warn), so the host-mesh tests skip it.
-        donate = (0, 1) if jax.default_backend() != "cpu" else ()
+        # blowing the 10-byte/param budget
         self._jit_step = jax.jit(
             make_train_step(self.model, self.tc, self.ctx),
-            donate_argnums=donate)
+            donate_argnums=(0, 1))
         # first dispatches after a (re)jit pay compilation + first-run
         # allocation — not steady-state timings; don't let them poison
         # the straggler EWMA

@@ -14,6 +14,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def run_sub(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # the child measures on a virtual CPU mesh; it must never reach for
+    # an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, env=env, timeout=560)
@@ -24,7 +27,7 @@ def run_sub(code: str, devices: int = 8) -> str:
 HEADER = """
 import dataclasses, jax, jax.numpy as jnp
 import numpy as np
-from repro.compat import make_mesh as mk
+from repro.launch.mesh import make_mesh as mk
 from repro.configs.base import get_config, smoke_config
 from repro.core import moe as moe_mod
 from repro.models.api import build_model
@@ -129,14 +132,14 @@ class TestCollectives:
         out = run_sub("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh as mk
 from repro.parallel import collectives
-mesh = make_mesh((4,), ("pod",))
+mesh = mk((4,), ("pod",))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 8, 256), jnp.float32)
 def f(xl):
     return collectives.compressed_psum(xl[0], "pod", n_bits=10)[None]
-y = shard_map(f, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-              check_vma=False)(x)
+y = jax.shard_map(f, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
+                  check_vma=False)(x)
 ref = x.sum(0)
 for i in range(4):
     rel = float(jnp.abs(y[i] - ref).max() / jnp.abs(ref).max())
@@ -148,9 +151,9 @@ print("compressed psum OK")
     def test_pipeline_fwd_and_grad(self):
         out = run_sub("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh as mk
 from repro.parallel import pipeline
-mesh = make_mesh((4,), ("pipe",))
+mesh = mk((4,), ("pipe",))
 Pn, M, mb, d = 4, 8, 2, 16
 Ws = jax.random.normal(jax.random.PRNGKey(0), (Pn, d, d)) * 0.3
 stage = lambda w, x: jnp.tanh(x @ w)

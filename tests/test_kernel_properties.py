@@ -7,10 +7,8 @@ fp8 quantize->dequantize round-trip error bounds, the exhaustive
 256-code pin behind ``paged.e4m3_decode``, and golden-value fixtures
 for the paged attention reference oracles.
 
-Runs with or without ``hypothesis``: draws come from seeded numpy
-PCG64 generators so CI without the library still executes the full
-sweep deterministically; when hypothesis *is* installed an extra fuzz
-pass widens shape coverage (see ``tests/_hypothesis_compat.py``).
+Draws come from seeded numpy PCG64 generators so the sweep is
+deterministic; a hypothesis fuzz pass widens shape coverage.
 """
 import zlib
 
@@ -19,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import kernels
 from repro.core import logfmt, paged
@@ -135,6 +133,9 @@ def _paged_gqa_args(gen, boundary):
     else:
         k_s = jnp.ones((pool + 1, page), jnp.float32)
         v_s = jnp.ones((pool + 1, page), jnp.float32)
+    # pools store one token's (KV, hd) entry as a flat row
+    k = k.reshape(pool + 1, page, KV * hd)
+    v = v.reshape(pool + 1, page, KV * hd)
     table = _paged_table(gen, B, pp, pool)
     args = (q, k, v, k_s, v_s, table, jnp.asarray(qpos))
     return args, dict(scale=0.13)
@@ -279,8 +280,7 @@ class TestPropertySweep:
     @settings(max_examples=8, deadline=None)
     @given(st.integers(min_value=0, max_value=2**31 - 1))
     def test_fuzz_paged_gqa_decode(self, seed):
-        """Hypothesis widens the seed space when available; skipped (not
-        failed) in containers without the library."""
+        """Hypothesis widens the seed space beyond the fixed sweep."""
         args, kwargs, compare = _sample_paged_gqa_decode(_gen("fuzz", seed))
         _run_case("paged_gqa_decode", args, kwargs, compare)
 
@@ -424,6 +424,8 @@ def _golden_gqa_inputs(fp8):
     else:
         k_s = jnp.ones((pool + 1, page), jnp.float32)
         v_s = jnp.ones((pool + 1, page), jnp.float32)
+    k = k.reshape(pool + 1, page, KV * hd)
+    v = v.reshape(pool + 1, page, KV * hd)
     table = jnp.asarray([[2, 4], [0, 3]], jnp.int32)
     qpos = jnp.asarray([2, 5], jnp.int32)
     return q, k, v, k_s, v_s, table, qpos

@@ -250,6 +250,9 @@ def _paged_gqa_case(dims, storage):
         else:
             k_s = jnp.ones((pool + 1, page), jnp.float32)
             v_s = jnp.ones((pool + 1, page), jnp.float32)
+        # pools store one token's (KV, hd) entry as a flat row
+        k = k.reshape(pool + 1, page, KV * hd)
+        v = v.reshape(pool + 1, page, KV * hd)
         ids = jax.random.permutation(jax.random.PRNGKey(7), pool)[:B * pp]
         table = ids.reshape(B, pp).astype(jnp.int32)
         qpos = jnp.arange(B, dtype=jnp.int32) * 3 + (pp * page) // 2

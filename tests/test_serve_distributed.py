@@ -34,6 +34,9 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")   # for benchmarks.*
 def run_sub(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # the child measures on a virtual CPU mesh; it must never reach for
+    # an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (SRC + os.pathsep + ROOT + os.pathsep
                          + env.get("PYTHONPATH", ""))
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -44,7 +47,7 @@ def run_sub(code: str, devices: int = 8) -> str:
 
 HEADER = """
 import dataclasses, numpy as np, jax
-from repro.compat import make_mesh as mk
+from repro.launch.mesh import make_mesh as mk
 from repro.configs.base import get_config, smoke_config
 from repro.parallel import context as pctx_mod
 from repro.serve.engine import Request, ServeEngine
@@ -262,7 +265,7 @@ class TestDecodeWireBytes:
         BENCH_serve.json)."""
         out = run_sub("""
 import jax
-from repro.compat import make_mesh as mk
+from repro.launch.mesh import make_mesh as mk
 from repro.parallel import context as pctx_mod
 from repro.serve.engine import ServeEngine
 from benchmarks.train_bench import bench_config
@@ -340,7 +343,8 @@ class TestDecodeOverlap:
         8-row dispatch capacity floor; equal once real rows dominate).
         Lowering-only: nothing is executed on the 8 fake devices."""
         out = run_sub("""
-from repro.compat import make_mesh as mk
+import jax
+from repro.launch.mesh import make_mesh as mk
 from repro.parallel import context as pctx_mod
 from repro.parallel import overlap
 from repro.serve.engine import ServeEngine

@@ -1,7 +1,10 @@
 """End-to-end system behaviour: train -> checkpoint -> serve round trip on
 the paper's full stack, MTP learns, cost-model calibration, config
-registry integrity."""
+registry integrity, and the chip smoke script's refusal of the CPU."""
 import dataclasses
+import os
+import subprocess
+import sys
 import tempfile
 
 import jax
@@ -123,10 +126,20 @@ def test_cost_model_calibration_unrolled():
     batch = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
              "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
     compiled = jax.jit(jax.grad(fwd)).lower(structs, batch).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):          # older jaxlib: one dict per computation
-        ca = ca[0]
-    xla_flops = ca.get("flops", 0)
+    xla_flops = compiled.cost_analysis().get("flops", 0)
     c = costs_mod.step_costs(cfg, shape, remat="none")
     ratio = xla_flops / c.flops_total
     assert 0.2 < ratio < 2.0, (xla_flops, c.flops_total)
+
+
+def test_chip_smoke_refuses_cpu():
+    """``chip_smoke.py`` must fail, naming the platform, before it builds
+    anything when JAX has no TPU: a smoke run that fell back to the CPU
+    would pass without proving anything about the chip."""
+    script = os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, script], capture_output=True,
+                       text=True, env=env, timeout=300)
+    assert r.returncode != 0
+    assert "platform 'cpu'" in r.stderr, r.stderr[-2000:]
+    assert r.stdout == "", r.stdout      # no phase ran, no result line

@@ -21,6 +21,9 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")   # for benchmarks.*
 def run_sub(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # the child measures on a virtual CPU mesh; it must never reach for
+    # an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (SRC + os.pathsep + ROOT + os.pathsep
                          + env.get("PYTHONPATH", ""))
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -32,7 +35,7 @@ def run_sub(code: str, devices: int = 8) -> str:
 HEADER = """
 import dataclasses, jax, jax.numpy as jnp
 import numpy as np
-from repro.compat import make_mesh as mk
+from repro.launch.mesh import make_mesh as mk
 from repro.configs.base import get_config, smoke_config
 from repro.parallel import context as pctx_mod
 from repro.train.trainer import Trainer, TrainConfig
@@ -293,7 +296,7 @@ class TestWireBytes:
         BENCH_train.json)."""
         out = run_sub("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh as mk
+from repro.launch.mesh import make_mesh as mk
 from repro.models.api import build_model
 from repro.parallel import context as pctx_mod, ep, overlap
 from benchmarks.train_bench import bench_config

@@ -133,7 +133,7 @@ class TestCheckpoint:
         tree = {"w": jax.random.normal(rng, (8, 4))}
         with tempfile.TemporaryDirectory() as d:
             ckpt.save(d, 1, tree)
-            from repro.compat import make_mesh
+            from repro.launch.mesh import make_mesh
             mesh = make_mesh((1,), ("data",))
             sh = {"w": NamedSharding(mesh, P("data", None))}
             got, _ = ckpt.restore(d, tree, shardings=sh)

@@ -18,7 +18,7 @@ from .engine import Diagnostic, Project, Rule, SourceFile
 # repo root as ``python -m tools.repro_lint src tests`` so relpaths look
 # like ``src/repro/serve/engine.py``; globs are written to also match
 # fixture trees rooted elsewhere (``*serve/engine.py``).
-TESTS = ("*tests/*", "*test_*.py", "*conftest.py", "*_hypothesis_compat.py")
+TESTS = ("*tests/*", "*test_*.py", "*conftest.py")
 
 
 # ---------------------------------------------------------------------------
