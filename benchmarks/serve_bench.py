@@ -129,14 +129,12 @@ def bench_arch(arch: str, *, slots: int = 2, max_len: int = 64,
             eng.admit_prefilled(r, first, cache1, eng.free_slots()[0])
         eng.step()
     wall = time.perf_counter() - tic
-    # pure steady-state decode: exclude admission work (splice dispatches,
-    # prefill-produced first tokens) so the metric is chunks per token —
-    # same accounting as launch/serve.py
-    decode_tokens = (eng.stats["tokens"] - s0["tokens"]
-                     - (eng.stats["first_tokens"] - s0["first_tokens"]))
-    decode_dispatches = (eng.stats["dispatches"] - s0["dispatches"]
-                         - (eng.stats["prefills"] - s0["prefills"])
-                         - (eng.stats["splices"] - s0["splices"]))
+    # pure steady-state decode: the engine's decode-chunk counters leave
+    # admission work (splice dispatches, prefill-produced first tokens)
+    # out, so the metric is chunks per token — as in launch/serve.py
+    decode_tokens = eng.stats["decode_tokens"] - s0["decode_tokens"]
+    decode_dispatches = (eng.stats["decode_dispatches"]
+                         - s0["decode_dispatches"])
 
     # parity-reference stream on the warm engine (fresh request objects)
     stream, _ = _stream(eng, cfg, requests, max_new)

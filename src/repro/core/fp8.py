@@ -118,10 +118,12 @@ def scaled_matmul_ref(xq, xs, wq, ws, tile: int = TILE) -> jax.Array:
     Mathematically identical to dequantize-then-matmul (scales are constant
     within each contraction tile), which is what we do — the Pallas kernel
     applies scales per-tile on the accumulator instead (the paper's
-    "inside the Tensor Core" version).
+    "inside the Tensor Core" version). The dequantization runs under the
+    ``fp8_quant`` scope, the GEMM outside it.
     """
-    x = dequant_tilewise(xq, xs, tile)
-    w = dequant_blockwise(wq, ws)
+    with jax.named_scope("fp8_quant"):
+        x = dequant_tilewise(xq, xs, tile)
+        w = dequant_blockwise(wq, ws)
     return jax.lax.dot_general(
         x, w, (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -136,8 +138,9 @@ def _matmul_qdq(x: jax.Array, w: jax.Array, impl: str) -> jax.Array:
         shape = x.shape
         y = fp8_ops.fp8_matmul(x.reshape(-1, shape[-1]), w)
         return y.reshape(shape[:-1] + (w.shape[-1],))
-    xq, xs = quantize_tilewise(x)
-    wq, ws = quantize_blockwise(w)
+    with jax.named_scope("fp8_quant"):
+        xq, xs = quantize_tilewise(x)
+        wq, ws = quantize_blockwise(w)
     return scaled_matmul_ref(xq, xs, wq, ws)
 
 

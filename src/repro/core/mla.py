@@ -261,16 +261,17 @@ def mla_paged_decode_step(p: dict, cache: dict, x: jax.Array, *,
     else:
         def write(pool, vals):
             return paged.page_write_chunk(pool, page_table, qpos, vals)
-    if fp8:
-        qc, sc = paged.quantize_vecs(ckv_new)
-        qk, sk = paged.quantize_vecs(kr_new)
-        new_cache["ckv"] = write(cache["ckv"], qc)
-        new_cache["kr"] = write(cache["kr"], qk)
-        new_cache["ckv_scale"] = write(cache["ckv_scale"], sc)
-        new_cache["kr_scale"] = write(cache["kr_scale"], sk)
-    else:
-        new_cache["ckv"] = write(cache["ckv"], ckv_new)
-        new_cache["kr"] = write(cache["kr"], kr_new)
+    with jax.named_scope("cache_write"):
+        if fp8:
+            qc, sc = paged.quantize_vecs(ckv_new)
+            qk, sk = paged.quantize_vecs(kr_new)
+            new_cache["ckv"] = write(cache["ckv"], qc)
+            new_cache["kr"] = write(cache["kr"], qk)
+            new_cache["ckv_scale"] = write(cache["ckv_scale"], sc)
+            new_cache["kr_scale"] = write(cache["kr_scale"], sk)
+        else:
+            new_cache["ckv"] = write(cache["ckv"], ckv_new)
+            new_cache["kr"] = write(cache["kr"], kr_new)
 
     q_abs = _absorb_queries(p, q_nope, cfg)
     scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)

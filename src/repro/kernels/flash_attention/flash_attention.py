@@ -111,6 +111,7 @@ def flash_prefill_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H * hd), jnp.float32),
         interpret=interpret,
+        name="flash_prefill",
     )(q.reshape(B, S, H * hd), k.reshape(B, T, KV * hd),
       v.reshape(B, T, KV * hd), q_pos.astype(jnp.int32).reshape(B, S, 1),
       k_pos.astype(jnp.int32).reshape(B, 1, T))

@@ -73,4 +73,5 @@ def fp8_gemm(xq: jax.Array, xs: jax.Array, wq: jax.Array, ws: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="fp8_gemm",
     )(xq, xs_col, wq, ws_row)

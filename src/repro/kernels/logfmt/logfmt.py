@@ -92,6 +92,7 @@ def logfmt_encode(x: jax.Array, *, n_bits: int = 8, bn: int = 32,
             jax.ShapeDtypeStruct((N, D // TILE), jnp.float32),
         ),
         interpret=interpret,
+        name="logfmt_encode",
     )(x)
 
 
@@ -114,4 +115,5 @@ def logfmt_decode(codes: jax.Array, mn: jax.Array, step: jax.Array, *,
         out_specs=pl.BlockSpec((bn, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), dtype),
         interpret=interpret,
+        name="logfmt_decode",
     )(codes, mn, step)

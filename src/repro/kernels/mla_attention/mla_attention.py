@@ -100,6 +100,7 @@ def mla_decode_kernel(q_abs: jax.Array, q_rope: jax.Array, ckv: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
         interpret=interpret,
+        name="mla_decode",
     )(qpos.astype(jnp.int32), q_abs.astype(jnp.float32),
       q_rope.astype(jnp.float32), ckv, kr,
       pos.astype(jnp.int32).reshape(B, 1, T))

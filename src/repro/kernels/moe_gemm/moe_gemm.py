@@ -58,4 +58,5 @@ def moe_gemm(x: jax.Array, w: jax.Array, *, bc: int = 128, bf: int = 256,
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
         interpret=interpret,
+        name="moe_gemm",
     )(x, w)

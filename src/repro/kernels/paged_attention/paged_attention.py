@@ -151,6 +151,7 @@ def paged_mla_decode_kernel(q_abs: jax.Array, q_rope: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
         interpret=interpret,
+        name="paged_mla_decode",
     )(table, qpos, qa, qr, ckv, kr, _row_scales(ckv_s), _row_scales(kr_s))
 
 
@@ -237,5 +238,6 @@ def paged_gqa_decode_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), jnp.float32),
         interpret=interpret,
+        name="paged_gqa_decode",
     )(table, qpos, qf, k, v, _row_scales(k_s), _row_scales(v_s))
     return out.reshape(B, H, hd)

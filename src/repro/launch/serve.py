@@ -224,14 +224,9 @@ def main():
               f"(EP degree {eng.ctx.model_size}), "
               f"moe_impl={args.moe_impl}, wire={args.wire}, decode "
               f"all-to-all {eng.decode_alltoall_bytes()} B/step (lowered)")
-    # admission-side dispatches: prefill (+ its page-quantize step when
-    # paged), splice/scatter, and page releases — exclude them so the
-    # figure is fused decode chunks per token
-    admit = (eng.stats["prefills"] * (2 if eng.paged else 1)
-             + eng.stats["splices"] + eng.stats["page_admits"]
-             + eng.stats["page_releases"])
-    decode_dispatches = eng.stats["dispatches"] - admit
-    decode_tokens = eng.stats["tokens"] - eng.stats["first_tokens"]
+    # fused decode chunks per token the chunks emitted
+    decode_dispatches = eng.stats["decode_dispatches"]
+    decode_tokens = eng.stats["decode_tokens"]
     if decode_tokens:
         print(f"[serve] decode dispatches/token = "
               f"{decode_dispatches / decode_tokens:.3f} "

@@ -363,12 +363,14 @@ class Model:
     def _unembed(self, params, h):
         from repro.parallel.context import shard_act
         emb = params["embed"]
-        h = Lyr.rmsnorm(shard_act(h), emb["final_norm"], self.cfg.rms_eps)
-        w = emb.get("unemb")
-        if w is None:
-            w = emb["emb"].T
-        logits = jnp.einsum("...d,dv->...v", h, w.astype(h.dtype))
-        return shard_act(logits, vocab_axis=True)
+        with jax.named_scope("unembed"):
+            h = Lyr.rmsnorm(shard_act(h), emb["final_norm"],
+                            self.cfg.rms_eps)
+            w = emb.get("unemb")
+            if w is None:
+                w = emb["emb"].T
+            logits = jnp.einsum("...d,dv->...v", h, w.astype(h.dtype))
+            return shard_act(logits, vocab_axis=True)
 
     def _encode(self, params, src_embeds):
         """Run the encoder stack (encdec family) over frame embeddings."""
@@ -408,7 +410,10 @@ class Model:
             xs = (p, None)
         else:
             xs = (p, cache)
-        x, (new_cache, stats) = jax.lax.scan(step, x, xs)
+        # the scope names the scan's own work too: slicing each layer's
+        # weights and cache out of the stacks and writing the cache back
+        with jax.named_scope("layers"):
+            x, (new_cache, stats) = jax.lax.scan(step, x, xs)
         return x, new_cache, stats
 
     # -- phases --------------------------------------------------------------
@@ -888,28 +893,30 @@ class Model:
                 cache["mtp"] = ring
             logits, cache = self.decode_step(params, cache, tok[:, None],
                                              pos[:, None])
-            # per-slot sampling keys: fold the slot's sample index into its
-            # request-scoped base key, so the token at stream index t is a
-            # pure function of (request seed, t) — a retried request
-            # re-dispatched on another replica reproduces its stream
-            keys = jax.vmap(jax.random.fold_in)(st["rngs"], st["tix"])
-            nxt = jax.vmap(sample)(logits[:, 0], keys)
-            if use_mtp:
-                drafts = st["drafts"] + active.sum(dtype=jnp.int32)
-                accepted = st["accepted"] + (
-                    active & (d == nxt)).sum(dtype=jnp.int32)
-            else:
-                drafts, accepted = st["drafts"], st["accepted"]
-            emitted = jnp.where(active, nxt, -1)
-            pos2 = pos + active
-            left2 = left - active
-            done = active & (((eos >= 0) & (nxt == eos)) | (left2 <= 0))
-            active2 = active & ~done
-            tok2 = jnp.where(active, nxt, tok)
-            st2 = dict(tokens=tok2, positions=pos2, active=active2,
-                       left=left2, eos=eos, rngs=st["rngs"],
-                       tix=st["tix"] + active, drafts=drafts,
-                       accepted=accepted)
+            with jax.named_scope("sample"):
+                # per-slot sampling keys: fold the slot's sample index into
+                # its request-scoped base key, so the token at stream index
+                # t is a pure function of (request seed, t) — a retried
+                # request re-dispatched on another replica reproduces its
+                # stream
+                keys = jax.vmap(jax.random.fold_in)(st["rngs"], st["tix"])
+                nxt = jax.vmap(sample)(logits[:, 0], keys)
+                if use_mtp:
+                    drafts = st["drafts"] + active.sum(dtype=jnp.int32)
+                    accepted = st["accepted"] + (
+                        active & (d == nxt)).sum(dtype=jnp.int32)
+                else:
+                    drafts, accepted = st["drafts"], st["accepted"]
+                emitted = jnp.where(active, nxt, -1)
+                pos2 = pos + active
+                left2 = left - active
+                done = active & (((eos >= 0) & (nxt == eos)) | (left2 <= 0))
+                active2 = active & ~done
+                tok2 = jnp.where(active, nxt, tok)
+                st2 = dict(tokens=tok2, positions=pos2, active=active2,
+                           left=left2, eos=eos, rngs=st["rngs"],
+                           tix=st["tix"] + active, drafts=drafts,
+                           accepted=accepted)
             return (cache, st2), (emitted, active)
 
         (cache, state), (toks, was_active) = jax.lax.scan(
@@ -974,18 +981,19 @@ class Model:
             return sample_logits(logits, key, temperature, top_k)
 
         def substep(logits, st):
-            keys = jax.vmap(jax.random.fold_in)(st["rngs"], st["tix"])
-            nxt = jax.vmap(sample)(logits[:, 0], keys)
-            active, left, eos = st["active"], st["left"], st["eos"]
-            emitted = jnp.where(active, nxt, -1)
-            left2 = left - active
-            done = active & (((eos >= 0) & (nxt == eos)) | (left2 <= 0))
-            st2 = dict(tokens=jnp.where(active, nxt, st["tokens"]),
-                       positions=st["positions"] + active,
-                       active=active & ~done, left=left2, eos=eos,
-                       rngs=st["rngs"], tix=st["tix"] + active,
-                       drafts=st["drafts"], accepted=st["accepted"])
-            return emitted, active, st2
+            with jax.named_scope("sample"):
+                keys = jax.vmap(jax.random.fold_in)(st["rngs"], st["tix"])
+                nxt = jax.vmap(sample)(logits[:, 0], keys)
+                active, left, eos = st["active"], st["left"], st["eos"]
+                emitted = jnp.where(active, nxt, -1)
+                left2 = left - active
+                done = active & (((eos >= 0) & (nxt == eos)) | (left2 <= 0))
+                st2 = dict(tokens=jnp.where(active, nxt, st["tokens"]),
+                           positions=st["positions"] + active,
+                           active=active & ~done, left=left2, eos=eos,
+                           rngs=st["rngs"], tix=st["tix"] + active,
+                           drafts=st["drafts"], accepted=st["accepted"])
+                return emitted, active, st2
 
         def body(carry, _):
             cA, cB, sA, sB = carry

@@ -170,13 +170,19 @@ def _ffn(p: dict, h: jax.Array, cfg: ModelConfig, ctx: Optional[dict] = None):
 
 def block_apply(p: dict, x: jax.Array, cfg: ModelConfig, ctx: dict,
                 cache=None):
-    """Generic (dense|moe) self-attention block."""
-    h, cache_out = _self_attention(p["attn"],
-                                   Lyr.rmsnorm(x, p["ln1"], cfg.rms_eps),
-                                   cfg, ctx, cache)
-    x = x + h
-    f, stats = _ffn(p, Lyr.rmsnorm(x, p["ln2"], cfg.rms_eps), cfg, ctx)
-    return x + f, cache_out, stats
+    """Generic (dense|moe) self-attention block. Each sub-block, its
+    residual add included (XLA may fuse the add into the output GEMM, and
+    a fusion carries its root op's scope), runs under a named scope that
+    the device trace's ops carry: ``attn`` and ``ffn``."""
+    with jax.named_scope("attn"):
+        h, cache_out = _self_attention(p["attn"],
+                                       Lyr.rmsnorm(x, p["ln1"], cfg.rms_eps),
+                                       cfg, ctx, cache)
+        x = x + h
+    with jax.named_scope("ffn"):
+        f, stats = _ffn(p, Lyr.rmsnorm(x, p["ln2"], cfg.rms_eps), cfg, ctx)
+        x = x + f
+    return x, cache_out, stats
 
 
 def cross_block_apply(p: dict, x: jax.Array, cfg: ModelConfig, ctx: dict,

@@ -57,6 +57,7 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.configs.base import ModelConfig
 from repro.core import paged as paged_mod
@@ -334,7 +335,14 @@ class ServeEngine:
                       "drafts": 0, "dispatches": 0, "prefills": 0,
                       "splices": 0, "first_tokens": 0, "page_admits": 0,
                       "page_releases": 0, "peak_pages_used": 0,
-                      "chunk_prefills": 0, "evictions": 0}
+                      "chunk_prefills": 0, "evictions": 0,
+                      # prompt positions prefilled, and positions computed
+                      # (bucket or chunk padding included)
+                      "prefill_tokens": 0, "prefill_padded_tokens": 0,
+                      # fused decode dispatches, their lanes (slots x
+                      # chunk each) and the tokens those lanes emitted
+                      "decode_dispatches": 0, "decode_lane_steps": 0,
+                      "decode_tokens": 0}
         # jit caches + trace counters (tests assert retrace bounds)
         self._prefill_fns: Dict[int, Any] = {}
         self._prefill_traces = 0
@@ -639,22 +647,27 @@ class ServeEngine:
         lengths = np.asarray([L], np.int32)
         self.stats["dispatches"] += 1
         self.stats["prefills"] += 1
-        logits, cache1 = self._get_prefill(bucket)(
-            self.params, jnp.asarray(toks), jnp.asarray(lengths),
-            extras or {})
-        if self.paged:
-            self.stats["dispatches"] += 1
-            cache1 = self._quant_fn(cache1)
+        self.stats["prefill_tokens"] += L
+        self.stats["prefill_padded_tokens"] += bucket
+        with _span("engine.prefill", rid=req.rid):
+            logits, cache1 = self._get_prefill(bucket)(
+                self.params, jnp.asarray(toks), jnp.asarray(lengths),
+                extras or {})
+            if self.paged:
+                self.stats["dispatches"] += 1
+                cache1 = self._quant_fn(cache1)
         # first token follows the same sampling policy as the fused loop:
         # stream index ``sample_offset`` of the request's seeded stream
         # (engine-rng split for seedless requests)
         from repro.models.api import sample_logits
-        if req.seed is not None:
-            sub = jax.random.fold_in(jax.random.PRNGKey(req.seed), offset)
-        else:
-            self._rng, sub = jax.random.split(self._rng)
-        first = int(sample_logits(logits[0, -1], sub, self.temperature,
-                                  self.top_k))
+        with _span("engine.sample_first", rid=req.rid):
+            if req.seed is not None:
+                sub = jax.random.fold_in(jax.random.PRNGKey(req.seed),
+                                         offset)
+            else:
+                self._rng, sub = jax.random.split(self._rng)
+            first = int(sample_logits(logits[0, -1], sub, self.temperature,
+                                      self.top_k))
         return first, cache1
 
     # -- admission ----------------------------------------------------------
@@ -783,27 +796,28 @@ class ServeEngine:
             req.done = True
             return
         self.stats["dispatches"] += 1
-        if self.paged:
-            alloc = self._alloc.alloc(n)
-            self._slot_pages[slot] = alloc
-            trash = self.pool_pages
-            row = np.full((self.pages_per_slot,), trash, np.int32)
-            row[:n] = alloc
-            # prefill pages beyond the reserved range (bucket > request
-            # budget) land in the trash page
-            n_p = jax.tree.leaves(cache1["pages"])[0].shape[1]
-            ids = np.asarray([alloc[i] if i < n else trash
-                              for i in range(n_p)], np.int32)
-            self.stats["page_admits"] += 1
-            used = self.pool_pages - self.free_pages()
-            self.stats["peak_pages_used"] = max(
-                self.stats["peak_pages_used"], used)
-            self.cache = self._scatter_fn(
-                self.cache, cache1["pages"], cache1["aux"],
-                jnp.asarray(ids), jnp.asarray(row), slot)
-        else:
-            self.stats["splices"] += 1
-            self.cache = self._splice_fn(self.cache, cache1, slot)
+        with _span("engine.scatter", rid=req.rid):
+            if self.paged:
+                alloc = self._alloc.alloc(n)
+                self._slot_pages[slot] = alloc
+                trash = self.pool_pages
+                row = np.full((self.pages_per_slot,), trash, np.int32)
+                row[:n] = alloc
+                # prefill pages beyond the reserved range (bucket > request
+                # budget) land in the trash page
+                n_p = jax.tree.leaves(cache1["pages"])[0].shape[1]
+                ids = np.asarray([alloc[i] if i < n else trash
+                                  for i in range(n_p)], np.int32)
+                self.stats["page_admits"] += 1
+                used = self.pool_pages - self.free_pages()
+                self.stats["peak_pages_used"] = max(
+                    self.stats["peak_pages_used"], used)
+                self.cache = self._scatter_fn(
+                    self.cache, cache1["pages"], cache1["aux"],
+                    jnp.asarray(ids), jnp.asarray(row), slot)
+            else:
+                self.stats["splices"] += 1
+                self.cache = self._splice_fn(self.cache, cache1, slot)
         self.positions[slot] = len(prompt)
         self._tokens[slot] = first
         self._left[slot] = max_new - 1
@@ -929,9 +943,13 @@ class ServeEngine:
         pos = np.arange(start, start + C, dtype=np.int32)[None]
         self.stats["dispatches"] += 1
         self.stats["chunk_prefills"] += 1
-        logits, self.cache = self._chunk_fn(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray([L], jnp.int32), jnp.asarray(ps["row"][None]), slot)
+        self.stats["prefill_tokens"] += end - start
+        self.stats["prefill_padded_tokens"] += C
+        with _span("engine.prefill", rid=req.rid):
+            logits, self.cache = self._chunk_fn(
+                self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(pos), jnp.asarray([L], jnp.int32),
+                jnp.asarray(ps["row"][None]), slot)
         # index the chunk's freshly-written full prompt pages: their
         # content is a bitwise-pure function of the token prefix under the
         # fixed chunk grid, and the write is already dispatched, so device
@@ -945,18 +963,20 @@ class ServeEngine:
         # graduation: the slot decodes from the next dispatch on, so its
         # real page-table row replaces the trash row now
         self.stats["dispatches"] += 1
-        self.cache = self._table_fn(self.cache, jnp.asarray(ps["row"]),
-                                    slot)
+        with _span("engine.scatter", rid=req.rid):
+            self.cache = self._table_fn(self.cache, jnp.asarray(ps["row"]),
+                                        slot)
         from repro.models.api import sample_logits
-        if req.seed is not None:
-            sub = jax.random.fold_in(jax.random.PRNGKey(req.seed),
-                                     ps["offset"])
-            base = jax.random.PRNGKey(req.seed)
-        else:
-            self._rng, sub = jax.random.split(self._rng)
-            self._rng, base = jax.random.split(self._rng)
-        first = int(sample_logits(logits[0, -1], sub, self.temperature,
-                                  self.top_k))
+        with _span("engine.sample_first", rid=req.rid):
+            if req.seed is not None:
+                sub = jax.random.fold_in(jax.random.PRNGKey(req.seed),
+                                         ps["offset"])
+                base = jax.random.PRNGKey(req.seed)
+            else:
+                self._rng, sub = jax.random.split(self._rng)
+                self._rng, base = jax.random.split(self._rng)
+            first = int(sample_logits(logits[0, -1], sub, self.temperature,
+                                      self.top_k))
         req.out.append(first)
         self.stats["tokens"] += 1
         self.stats["first_tokens"] += 1
@@ -1493,12 +1513,23 @@ class ServeEngine:
         prefetches with leftover pages, and harvest cold prefix pages —
         then decode as usual with spilling slots masked out. Fetches are
         restarted once more after decode so pages freed by completions
-        this tick are already in flight by the next."""
+        this tick are already in flight by the next.
+
+        The tick runs under the profiler span ``engine.step``, its phases
+        under ``engine.admit`` (holding ``engine.prefill``,
+        ``engine.sample_first`` and ``engine.scatter`` per refill, each
+        with the request's ``rid``), ``engine.decode``, ``engine.sync``
+        and ``engine.harvest`` (docs/serving.md, Tracing)."""
+        with _span("engine.step"):
+            self._step()
+
+    def _step(self):
         if self.tier is not None:
             self._tick += 1
             self.tier_faults.on_tick()
             self._advance_transfers()
-            admitted = self._admit_pending()
+            with _span("engine.admit"):
+                admitted = self._admit_pending()
             resumed = self._resume_ready()
             if (not admitted and not resumed and self.free_slots()
                     and any(e["state"] in ("host", "fetching")
@@ -1515,7 +1546,8 @@ class ServeEngine:
             self.tstats["peak_resident_pages"] = max(
                 self.tstats["peak_resident_pages"], live)
         else:
-            self._admit_pending()
+            with _span("engine.admit"):
+                self._admit_pending()
         if self._prefilling:
             # one chunk for one long-prompt admission per tick, so
             # resident decode streams keep flowing between chunks (no
@@ -1530,17 +1562,30 @@ class ServeEngine:
                    for i, r in enumerate(self.active)):
             return
         self.stats["dispatches"] += 1
-        toks, emitted, self.cache, st = self._decode_fn(
-            self.params, self.cache, self._device_state())
-        # single host sync per chunk: emitted tokens + updated slot state
-        # — THE allowlisted dispatch point (1/chunk dispatches per token,
-        # asserted by tests/test_serve_fused.py and BENCH_serve.json)
-        # repro-lint: disable=R1-host-sync -- the one sync per chunk
-        toks, emitted, host = jax.device_get(
-            (toks, emitted, {k: st[k] for k in
-                             ("tokens", "positions", "active", "left",
-                              "tix", "drafts", "accepted")}))
+        self.stats["decode_dispatches"] += 1
+        self.stats["decode_lane_steps"] += self.slots * self.chunk
+        with _span("engine.decode"):
+            toks, emitted, self.cache, st = self._decode_fn(
+                self.params, self.cache, self._device_state())
+        with _span("engine.sync"):
+            # single host sync per chunk: emitted tokens + updated slot
+            # state — THE allowlisted dispatch point (1/chunk dispatches
+            # per token, asserted by tests/test_serve_fused.py and
+            # BENCH_serve.json)
+            # repro-lint: disable=R1-host-sync -- the one sync per chunk
+            toks, emitted, host = jax.device_get(
+                (toks, emitted, {k: st[k] for k in
+                                 ("tokens", "positions", "active", "left",
+                                  "tix", "drafts", "accepted")}))
+        with _span("engine.harvest"):
+            self._harvest(toks, emitted, host)
+
+    def _harvest(self, toks, emitted, host):
+        """Fold one decode chunk's host copy into the engine: counters,
+        the per-slot mirrors, each request's tokens, and the release of
+        finished slots."""
         self.stats["steps"] += int(emitted.any(axis=0).sum())
+        self.stats["decode_tokens"] += int(emitted.sum())
         self.stats["drafts"] += int(host["drafts"])
         self.stats["accepted_drafts"] += int(host["accepted"])
         # copy: device_get arrays are read-only, mirrors are written on

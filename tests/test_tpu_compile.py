@@ -9,6 +9,8 @@ test workers each import every test file. Compiles go through the
 with the persistent compilation cache off (a compile for a described
 chip is written to it but cannot be read back without one).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -88,3 +90,22 @@ def test_paged_kernel_page_sizes(name, storage, page, one_chip):
                   for a in args)
     used = compiled.memory_analysis().argument_size_in_bytes
     assert used <= 1.10 * nominal, (used, nominal)
+
+
+def test_paged_mla_kernel_carries_its_registry_name(one_chip):
+    """The kernel's HLO instruction (and so its device-trace event) is
+    named after its registry op, whatever its Python function is called."""
+    out = {}
+
+    def args_of(key):
+        args, kwargs = chip_cases.paged_mla_args(key, page=64,
+                                                 storage="fp8")
+        out["kwargs"] = kwargs
+        return args
+
+    args = jax.eval_shape(args_of, jax.random.PRNGKey(0))
+    text = _compile("paged_mla_decode", args, out["kwargs"],
+                    one_chip).as_text()
+    names = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert [re.sub(r"\.\d+$", "", n) for n in names] == ["paged_mla_decode"]
