@@ -24,7 +24,7 @@ training path: both backward GEMMs quantize via ``scaled_matmul_ref``).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +127,43 @@ def scaled_matmul_ref(xq, xs, wq, ws, tile: int = TILE) -> jax.Array:
     return jax.lax.dot_general(
         x, w, (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+
+
+class BlockQuantized(NamedTuple):
+    """A weight held as ``quantize_blockwise``'s pair, computed once.
+
+    Leading dims are stack dims (a segment's layers): as a pytree node the
+    pair is sliced by a layer scan like the array it replaces.
+    """
+    q: jax.Array       # (..., m, n) E4M3
+    scale: jax.Array   # (..., ceil(m/BLOCK), ceil(n/BLOCK)) fp32
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return self.q.shape
+
+
+def is_quantized(w) -> bool:
+    return isinstance(w, BlockQuantized)
+
+
+def quantize_weight(w) -> BlockQuantized:
+    """``quantize_blockwise`` over each trailing (m, n) matrix of ``w``;
+    an already-quantized pair passes through unchanged."""
+    if is_quantized(w):
+        return w
+    f = quantize_blockwise
+    for _ in range(w.ndim - 2):
+        f = jax.vmap(f)
+    return BlockQuantized(*f(w))
+
+
+def prequantized_linear(x: jax.Array, w: BlockQuantized) -> jax.Array:
+    """``fp8_linear``'s forward on a weight quantized ahead of time: the
+    activations still take per-call 1x128 tile scales."""
+    with jax.named_scope("fp8_quant"):
+        xq, xs = quantize_tilewise(x)
+    return scaled_matmul_ref(xq, xs, w.q, w.scale).astype(x.dtype)
 
 
 def _matmul_qdq(x: jax.Array, w: jax.Array, impl: str) -> jax.Array:

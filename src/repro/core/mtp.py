@@ -142,8 +142,9 @@ def mtp_align_head(params: dict) -> dict:
     m = dict(jax.tree.map(jnp.zeros_like, params["mtp"]))
     n, d2, d = params["mtp"]["w_proj"].shape
     proj = jnp.concatenate([jnp.eye(d), jnp.zeros((d2 - d, d))], axis=0)
+    # in the param dtype: a serving engine's w_proj may be an fp8 pair
     m["w_proj"] = jnp.broadcast_to(proj, (n, d2, d)).astype(
-        params["mtp"]["w_proj"].dtype)
+        params["mtp"]["norm_h"].dtype)
     m["norm_h"] = jnp.ones_like(params["mtp"]["norm_h"])
     p = dict(params)
     p["mtp"] = m

@@ -7,12 +7,13 @@ All layers are functional: ``*_specs(cfg)`` returns a ParamSpec pytree,
 from __future__ import annotations
 
 import math
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core import fp8
 from repro.models.param import ParamSpec
 
 # ---------------------------------------------------------------------------
@@ -31,20 +32,53 @@ def act_fn(name: str):
     return {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[name]
 
 
-def linear(x: jax.Array, w: jax.Array, cfg: Optional[ModelConfig] = None,
+def linear(x: jax.Array, w: Union[jax.Array, fp8.BlockQuantized],
+           cfg: Optional[ModelConfig] = None,
            b: Optional[jax.Array] = None) -> jax.Array:
     """Dense GEMM; routes through the FP8 fine-grained-scaled path (paper
     T4) when the config enables it. With ``cfg.fp8_impl='pallas'`` the
     GEMM dispatches through the kernel registry (``repro.kernels``) —
-    backend selection lives there, not in layer code."""
-    if cfg is not None and cfg.fp8 and w.ndim == 2 and x.shape[-1] >= 256:
-        from repro.core import fp8
+    backend selection lives there, not in layer code. A weight already
+    held as ``fp8.BlockQuantized`` (serving, see ``fp8_weights``)
+    skips its per-call quantization; the GEMM's operands are unchanged."""
+    if fp8.is_quantized(w):
+        y = fp8.prequantized_linear(x, w)
+    elif cfg is not None and cfg.fp8 and w.ndim == 2 and x.shape[-1] >= 256:
         y = fp8.fp8_linear(x, w, impl=cfg.fp8_impl)
     else:
         y = jnp.einsum("...d,df->...f", x, w.astype(x.dtype))
     if b is not None:
         y = y + b.astype(y.dtype)
     return y
+
+
+# The weights each block passes to ``linear``, by the block's key. MLA's
+# ``w_uk`` / ``w_uv`` are left out: decode absorbs them in f32 einsums.
+_LINEAR_WEIGHTS = {
+    "attn": ("wq", "wk", "wv", "wo", "w_dq", "w_uq", "w_dkv", "w_kr", "w_o"),
+    "xattn": ("wq", "wk", "wv", "wo"),
+    "mlp": ("w_gate", "w_up", "w_down"),
+    "mtp": ("w_proj",),
+}
+
+
+def fp8_weights(params, cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """The bf16 weight stacks that ``linear`` sends to ``fp8_linear``'s
+    jnp path, by ``keystr`` path: those a server may hold quantized once.
+    The Pallas impl quantizes inside its kernel op, so it gets none."""
+    out: Dict[str, jax.Array] = {}
+    if not (cfg.fp8 and cfg.fp8_impl == "ref"):
+        return out
+
+    def visit(path, leaf):
+        names = [getattr(k, "key", None) for k in path]
+        if (len(names) >= 2 and not fp8.is_quantized(leaf)
+                and names[-1] in _LINEAR_WEIGHTS.get(names[-2], ())
+                and leaf.shape[-2] >= 256):
+            out[jax.tree_util.keystr(path)] = leaf
+
+    jax.tree_util.tree_map_with_path(visit, params, is_leaf=fp8.is_quantized)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -83,6 +83,16 @@ def param_shardings(mesh: Mesh, spec_tree, rules: Dict[str, Rule]):
         spec_tree, is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
+def block_scale_sharding(s: NamedSharding, shape) -> NamedSharding:
+    """Sharding of a weight's per-block scales (``core/fp8``): the
+    weight's own spec, replicated along a dim whose block count its mesh
+    axes do not divide."""
+    spec = tuple(s.spec) + (None,) * (len(shape) - len(s.spec))
+    return NamedSharding(s.mesh, P(*(
+        ax if dim % _mesh_size(s.mesh, ax) == 0 else None
+        for dim, ax in zip(shape, spec))))
+
+
 def batch_pspec(mesh: Mesh, batch_size: int, dp_axes: Tuple[str, ...],
                 ndim: int = 2, seq_axis: Optional[str] = None) -> P:
     """Shard the batch dim over dp axes when divisible; optionally shard the

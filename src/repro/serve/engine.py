@@ -60,7 +60,9 @@ import numpy as np
 from jax.profiler import TraceAnnotation as _span
 
 from repro.configs.base import ModelConfig
+from repro.core import fp8 as fp8_mod
 from repro.core import paged as paged_mod
+from repro.models import layers as Lyr
 from repro.models.api import Model, build_model
 from repro.parallel import context as pctx_mod
 from repro.serve import tier as tier_mod
@@ -210,6 +212,7 @@ class ServeEngine:
         self.params = (params if params is not None
                        else self.model.init(jax.random.PRNGKey(seed),
                                             self._param_shardings))
+        self._install_params()
         self.slots = slots
         self.max_len = max_len
         self.use_mtp = use_mtp and cfg.mtp is not None
@@ -342,7 +345,9 @@ class ServeEngine:
                       # fused decode dispatches, their lanes (slots x
                       # chunk each) and the tokens those lanes emitted
                       "decode_dispatches": 0, "decode_lane_steps": 0,
-                      "decode_tokens": 0}
+                      "decode_tokens": 0,
+                      # bytes of the fp8 GEMM weights held quantized
+                      "fp8_prequantized_bytes": self._fp8_weight_bytes}
         # jit caches + trace counters (tests assert retrace bounds)
         self._prefill_fns: Dict[int, Any] = {}
         self._prefill_traces = 0
@@ -507,14 +512,63 @@ class ServeEngine:
             decode_chunk, donate_argnums=(1, 2),
             out_shardings=decode_out)
 
-    # -- mesh install --------------------------------------------------------
-    def _install_mesh(self):
-        """Shard the engine's whole working set over ``ctx.mesh``:
+    # -- params and mesh install ---------------------------------------------
+    def _install_params(self):
+        """Place the params and quantize the fp8 GEMM weights once.
 
-        * params per ``sharding.serve_rules`` — heads / dense matmuls /
-          vocab TP over the model axis, experts EP on the model axis (the
-          paper's decode deployment: no cross-node TP, attention
-          data-parallel across the EP group);
+        Meshed engines shard the params per ``sharding.serve_rules`` —
+        heads / dense matmuls / vocab TP over the model axis, experts EP
+        on the model axis (the paper's decode deployment: no cross-node
+        TP, attention data-parallel across the EP group). Each weight
+        stack that ``linear`` sends to the fp8 GEMM
+        (``layers.fp8_weights``) is then held as its E4M3 blocks and
+        block scales (``fp8.BlockQuantized``), quantized here under one
+        jit instead of inside every prefill and decode step; ``q`` keeps
+        the weight's sharding. Pairs handed in by another engine pass
+        through.
+        """
+        from repro.parallel import sharding
+        is_pair = fp8_mod.is_quantized
+
+        def pair(s, shape):
+            return fp8_mod.BlockQuantized(
+                s, sharding.block_scale_sharding(s, shape))
+
+        if self.meshed:
+            placed = jax.tree.map(
+                lambda s, w: pair(s, w.scale.shape) if is_pair(w) else s,
+                self._param_shardings, self.params)
+            # caller-supplied params may live anywhere; engine-drawn
+            # ones are already in place and this is a no-op
+            # repro-lint: disable=R1-host-sync -- one-time mesh install
+            # at engine construction, not a decode-loop transfer
+            self.params = jax.device_put(self.params, placed)
+        weights = Lyr.fp8_weights(self.params, self.cfg)
+        if weights:
+            def quantize(ws):
+                return {k: fp8_mod.quantize_weight(w) for k, w in ws.items()}
+
+            out = None
+            if self.meshed:
+                shapes = jax.eval_shape(quantize, weights)
+                out = {k: pair(w.sharding, shapes[k].scale.shape)
+                       for k, w in weights.items()}
+            # the bf16 stacks stay the caller's (or are dropped): there is
+            # no buffer to donate
+            # repro-lint: disable=R2-jit-contract -- one-time build step
+            done = jax.jit(quantize, out_shardings=out)(weights)
+            self.params = jax.tree_util.tree_map_with_path(
+                lambda path, w: done.get(jax.tree_util.keystr(path), w),
+                self.params, is_leaf=is_pair)
+        self._fp8_weight_bytes = sum(
+            w.q.nbytes + w.scale.nbytes
+            for w in jax.tree.leaves(self.params, is_leaf=is_pair)
+            if is_pair(w))
+
+    def _install_mesh(self):
+        """Shard the engine's working set over ``ctx.mesh`` (the params
+        were placed by :meth:`_install_params`):
+
         * dense cache per ``sharding.cache_pspecs`` (slot axis over dp,
           cache length over model), or the paged pools per
           ``sharding.paged_cache_pspecs`` (KV-head axes over model,
@@ -529,11 +583,6 @@ class ServeEngine:
         from repro.parallel import sharding
         ctx = self.ctx
         mesh = ctx.mesh
-        # caller-supplied params may live anywhere; engine-drawn ones are
-        # already in place and this is a no-op
-        # repro-lint: disable=R1-host-sync -- one-time mesh install at
-        # engine construction, not a decode-loop transfer
-        self.params = jax.device_put(self.params, self._param_shardings)
         model_axis = ctx.tp_axis or "model"
         if self.paged:
             self._cache_shardings = sharding.paged_cache_pspecs(
