@@ -22,9 +22,23 @@ attendable iff it is ``<= qpos[b]`` (paged caches never ring-wrap, so
 everything at or below the current decode position was written by this
 slot; trash/stale rows all sit above it).
 
-HBM traffic is one pass over the slot's *resident* pages at 1 byte/elem
+The MLA kernel's work follows ``qpos``: slot ``b``'s last live page is
+``last = max(qpos[b], 0) // page`` (capped at ``pp - 1``), and every row
+of a later page lies above ``qpos``. Its page index maps read
+``table[b, min(t, last)]``, so a dead step ``t > last`` repeats the
+previous step's block index and the pipeline issues no DMA for it; its
+dots and softmax update sit under ``pl.when(t <= last)``. A dead step
+still costs one grid step's bookkeeping (the index map's scalar
+arithmetic and the ``pl.when`` tests), not a page fetch or a dot. The
+output is bitwise what a walk over all ``pp`` pages gives for a finite
+pool (a dead page only folds ``acc * 1 + 0`` and ``l * 1 + 0``), and a
+dead page's bytes, NaN included, never reach it. A slot with
+``qpos < 0`` computes its first page fully masked and emits zeros.
+
+HBM traffic is one pass over the slot's *live* pages at 1 byte/elem
 (+4/token scales) — the memory-bound decode path the paper's Table 1 /
-§2.3.2 roofline is about, at roughly half the bf16 bytes.
+§2.3.2 roofline is about, at roughly half the bf16 bytes. The GQA
+kernel still walks all ``pp`` pages.
 
 TPU tiling: every block's last two dims are either whole array dims or
 (8, 128)-aligned. Pools are ``(P+1, page, width)`` so a page block
@@ -75,10 +89,17 @@ def _online_softmax_step(s, valid, v_scaled_p, m_ref, l_ref, acc_ref, v):
     m_ref[...] = m_new
 
 
+def _last_live_page(qpos, page: int, pp: int):
+    """A slot's last live page: ``max(qpos, 0) // page``, capped at
+    ``pp - 1``. Every row of a later page lies above ``qpos``."""
+    return jnp.minimum(jax.lax.div(jnp.maximum(qpos, 0), page), pp - 1)
+
+
 def _kernel(table_ref, qpos_ref, qa_ref, qr_ref, ckv_ref, kr_ref,
             cs_ref, ks_ref, o_ref, m_ref, l_ref, acc_ref, *, page: int):
     b = pl.program_id(0)
     t = pl.program_id(1)
+    pp = pl.num_programs(1)
 
     @pl.when(t == 0)
     def _init():
@@ -86,18 +107,20 @@ def _kernel(table_ref, qpos_ref, qa_ref, qr_ref, ckv_ref, kr_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ckv = ckv_ref[0].astype(jnp.float32)               # (page, R)
-    kr = kr_ref[0].astype(jnp.float32)                 # (page, Rr)
-    cs = cs_ref[0]                                     # (1, page)
-    s = (dot_nt(qa_ref[0], ckv) * cs
-         + dot_nt(qr_ref[0], kr) * ks_ref[0])       # (H, page)
-    # positional validity: logical row index vs current decode position
-    lpos = t * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-    valid = lpos <= qpos_ref[b]                        # (1, page)
-    _online_softmax_step(s, valid, lambda p: p * cs, m_ref, l_ref, acc_ref,
-                         ckv)
+    @pl.when(t <= _last_live_page(qpos_ref[b], page, pp))
+    def _live():
+        ckv = ckv_ref[0].astype(jnp.float32)           # (page, R)
+        kr = kr_ref[0].astype(jnp.float32)             # (page, Rr)
+        cs = cs_ref[0]                                 # (1, page)
+        s = (dot_nt(qa_ref[0], ckv) * cs
+             + dot_nt(qr_ref[0], kr) * ks_ref[0])   # (H, page)
+        # positional validity: logical row index vs current decode position
+        lpos = t * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        valid = lpos <= qpos_ref[b]                    # (1, page)
+        _online_softmax_step(s, valid, lambda p: p * cs, m_ref, l_ref,
+                             acc_ref, ckv)
 
-    @pl.when(t == pl.num_programs(1) - 1)
+    @pl.when(t == pp - 1)
     def _emit():
         o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
@@ -117,7 +140,10 @@ def paged_mla_decode_kernel(q_abs: jax.Array, q_rope: jax.Array,
     """q_abs (B,H,R) / q_rope (B,H,Rr); ckv (P+1, page, R) and kr (P+1,
     page, Rr) in E4M3 or the native dtype; ckv_s/kr_s (P+1, page) fp32
     per-token scales (ones for native storage); table (B, pp) physical
-    page ids; qpos (B,). Returns the latent output (B, H, R) fp32."""
+    page ids; qpos (B,). Returns the latent output (B, H, R) fp32.
+    Grid (B, pp), of which only slot ``b``'s live pages ``t <=
+    max(qpos[b], 0) // page`` are fetched and computed (module
+    docstring)."""
     B, H, R = q_abs.shape
     Rr = q_rope.shape[-1]
     page = ckv.shape[1]
@@ -128,16 +154,22 @@ def paged_mla_decode_kernel(q_abs: jax.Array, q_rope: jax.Array,
     qa = q_abs.astype(jnp.float32) * scale
     qr = q_rope.astype(jnp.float32) * scale
 
+    def live_page(b, t, tbl, qp):
+        # a dead step repeats the last live page's block index, so the
+        # pipeline fetches nothing for it
+        return (tbl[b, jnp.minimum(t, _last_live_page(qp[b], page, pp))],
+                0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                     # table, qpos
         grid=(B, pp),
         in_specs=[
             pl.BlockSpec((1, H, R), lambda b, t, tbl, qp: (b, 0, 0)),
             pl.BlockSpec((1, H, Rr), lambda b, t, tbl, qp: (b, 0, 0)),
-            pl.BlockSpec((1, page, R), lambda b, t, tbl, qp: (tbl[b, t], 0, 0)),
-            pl.BlockSpec((1, page, Rr), lambda b, t, tbl, qp: (tbl[b, t], 0, 0)),
-            pl.BlockSpec((1, 1, page), lambda b, t, tbl, qp: (tbl[b, t], 0, 0)),
-            pl.BlockSpec((1, 1, page), lambda b, t, tbl, qp: (tbl[b, t], 0, 0)),
+            pl.BlockSpec((1, page, R), live_page),
+            pl.BlockSpec((1, page, Rr), live_page),
+            pl.BlockSpec((1, 1, page), live_page),
+            pl.BlockSpec((1, 1, page), live_page),
         ],
         out_specs=pl.BlockSpec((1, H, R), lambda b, t, tbl, qp: (b, 0, 0)),
         scratch_shapes=[

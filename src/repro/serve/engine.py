@@ -198,6 +198,9 @@ class ServeEngine:
             self.model.impl_ctx = {"gqa_impl": attn_impl,
                                    "mla_impl": attn_impl}
         self.attn_impl = attn_impl
+        # paged decode of MLA layers runs the paged_mla_decode kernel
+        self._mla_kernel_decode = (paged and attn_impl == "pallas"
+                                   and cfg.attention == "mla")
         self.ctx = ctx
         self.meshed = ctx is not None and ctx.mesh is not None
         self._param_shardings = None
@@ -346,6 +349,11 @@ class ServeEngine:
                       # chunk each) and the tokens those lanes emitted
                       "decode_dispatches": 0, "decode_lane_steps": 0,
                       "decode_tokens": 0,
+                      # paged_mla_decode's pages: those it computes on
+                      # (each lane's live pages, every step of a chunk)
+                      # and its whole grid (slots x pages_per_slot x
+                      # chunk); 0 unless that kernel runs the decode
+                      "decode_live_pages": 0, "decode_grid_pages": 0,
                       # bytes of the fp8 GEMM weights held quantized
                       "fp8_prequantized_bytes": self._fp8_weight_bytes}
         # jit caches + trace counters (tests assert retrace bounds)
@@ -1635,6 +1643,17 @@ class ServeEngine:
         finished slots."""
         self.stats["steps"] += int(emitted.any(axis=0).sum())
         self.stats["decode_tokens"] += int(emitted.sum())
+        if self._mla_kernel_decode:
+            # each step's qpos: the dispatched positions, advanced by the
+            # lane's active steps before it; the kernel's rule computes
+            # pages 0..max(qpos, 0) // page of every lane
+            qpos = (self.positions[:, None] + np.cumsum(emitted, axis=1)
+                    - emitted)
+            last = np.minimum(np.maximum(qpos, 0) // self.page_size,
+                              self.pages_per_slot - 1)
+            self.stats["decode_live_pages"] += int((last + 1).sum())
+            self.stats["decode_grid_pages"] += (
+                self.slots * self.pages_per_slot * self.chunk)
         self.stats["drafts"] += int(host["drafts"])
         self.stats["accepted_drafts"] += int(host["accepted"])
         # copy: device_get arrays are read-only, mirrors are written on

@@ -10,6 +10,7 @@ for the paged attention reference oracles.
 Draws come from seeded numpy PCG64 generators so the sweep is
 deterministic; a hypothesis fuzz pass widens shape coverage.
 """
+import functools
 import zlib
 
 import jax
@@ -18,9 +19,12 @@ import numpy as np
 import pytest
 
 from hypothesis import given, settings, strategies as st
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import kernels
 from repro.core import logfmt, paged
+from repro.kernels.paged_attention import paged_attention as pa
 from repro.kernels.paged_attention.ref import (paged_gqa_decode_ref,
                                                paged_mla_decode_ref)
 
@@ -283,6 +287,140 @@ class TestPropertySweep:
         """Hypothesis widens the seed space beyond the fixed sweep."""
         args, kwargs, compare = _sample_paged_gqa_decode(_gen("fuzz", seed))
         _run_case("paged_gqa_decode", args, kwargs, compare)
+
+
+# ---------------------------------------------------------------------------
+# paged_mla_decode works on each slot's live pages only
+# ---------------------------------------------------------------------------
+
+
+def _full_walk_kernel(table_ref, qpos_ref, qa_ref, qr_ref, ckv_ref, kr_ref,
+                      cs_ref, ks_ref, o_ref, m_ref, l_ref, acc_ref, *, page):
+    """The MLA kernel's body without its live-page rule: every grid step
+    fetches ``table[b, t]`` and folds it in, dead or not."""
+    b, t = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, pa.NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    ckv = ckv_ref[0].astype(jnp.float32)
+    kr = kr_ref[0].astype(jnp.float32)
+    cs = cs_ref[0]
+    s = (pa.dot_nt(qa_ref[0], ckv) * cs
+         + pa.dot_nt(qr_ref[0], kr) * ks_ref[0])
+    lpos = t * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+    pa._online_softmax_step(s, lpos <= qpos_ref[b], lambda p: p * cs,
+                            m_ref, l_ref, acc_ref, ckv)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _emit():
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _full_walk(qa, qr, ckv, kr, cs, ks, table, qpos, *, scale):
+    """``paged_mla_decode`` as a walk over all ``pp`` pages of every slot
+    (interpret mode): the numbers the live-page rule must keep."""
+    (B, H, R), Rr, page = qa.shape, qr.shape[-1], ckv.shape[1]
+    page_map = lambda b, t, tbl, qp: (tbl[b, t], 0, 0)  # noqa: E731
+    slot_map = lambda b, t, tbl, qp: (b, 0, 0)          # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, table.shape[1]),
+        in_specs=[pl.BlockSpec((1, H, R), slot_map),
+                  pl.BlockSpec((1, H, Rr), slot_map),
+                  pl.BlockSpec((1, page, R), page_map),
+                  pl.BlockSpec((1, page, Rr), page_map),
+                  pl.BlockSpec((1, 1, page), page_map),
+                  pl.BlockSpec((1, 1, page), page_map)],
+        out_specs=pl.BlockSpec((1, H, R), slot_map),
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, R), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_full_walk_kernel, page=page),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
+        interpret=True,
+    )(table, qpos, qa.astype(jnp.float32) * scale,
+      qr.astype(jnp.float32) * scale, ckv, kr, pa._row_scales(cs),
+      pa._row_scales(ks))
+
+
+def _interpret_mla(args, kwargs):
+    with kernels.use_backend("interpret", clear_caches=False):
+        return np.asarray(kernels.get("paged_mla_decode")(*args, **kwargs))
+
+
+def _assert_bitwise(got, want):
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+# the property samples: the sweep's two draws and each named boundary
+MLA_SAMPLES = [("sweep", 0), ("sweep", 1)] + [(b, None)
+                                              for b in PAGED_BOUNDARIES]
+
+
+def _mla_sample(kind, seed):
+    if kind == "sweep":
+        args, kwargs, _ = _sample_paged_mla_decode(
+            _gen("paged_mla_decode", seed))
+        return args, kwargs
+    return _paged_mla_args(_gen("paged_mla_decode", kind), kind)
+
+
+class TestPagedMLALivePages:
+    @pytest.mark.parametrize("kind,seed", MLA_SAMPLES)
+    def test_bitwise_equal_to_full_walk(self, kind, seed):
+        """Skipping dead pages changes no bit of a finite pool's output."""
+        args, kwargs = _mla_sample(kind, seed)
+        _assert_bitwise(_interpret_mla(args, kwargs),
+                        np.asarray(_full_walk(*args, **kwargs)))
+
+    @pytest.mark.parametrize("case", ["zero", "page_minus_1", "page",
+                                      "last_row", "negative"])
+    def test_dead_pages_never_reach_the_output(self, case):
+        """Lane 0 sits at the case's ``qpos``; lanes 1 and 2 reserve a
+        few pages past their last live one and point the rest of their
+        row at the trash page. Every dead page (reserved above ``qpos``,
+        or trash) holds e4m3 NaN bytes and NaN scales, and the output is
+        bitwise that of zeros there, which is the full walk's."""
+        gen = _gen("live_pages", case)
+        B, pp, page, H, R, Rr = 3, 4, 8, 4, 32, 8
+        pool = B * pp
+        qpos = np.array([{"zero": 0, "page_minus_1": page - 1,
+                          "page": page, "last_row": pp * page - 1,
+                          "negative": -1}[case],
+                         page + 3, 2 * page], np.int32)
+        last = np.maximum(qpos, 0) // page          # all below pp
+        ids = gen.permutation(pool).reshape(B, pp)
+        table = ids.copy()
+        for b in (1, 2):
+            table[b, last[b] + 2:] = pool           # the trash page
+        dead = np.arange(pp)[None, :] > last[:, None]
+        dead_ids = np.unique(table[dead])
+        assert pool in dead_ids and dead_ids.size > 1
+        ckv, cs = paged.quantize_vecs(_normal(gen, (pool + 1, page, R)))
+        kr, ks = paged.quantize_vecs(_normal(gen, (pool + 1, page, Rr)))
+        nan8 = jax.lax.bitcast_convert_type(jnp.uint8(0x7F), paged.E4M3)
+
+        def fill(x, v):
+            return x.at[dead_ids].set(jnp.asarray(v, x.dtype))
+
+        q = (_normal(gen, (B, H, R)), _normal(gen, (B, H, Rr)))
+        rest = (jnp.asarray(table, jnp.int32), jnp.asarray(qpos))
+        zeros = (fill(ckv, 0), fill(kr, 0), fill(cs, 0), fill(ks, 0))
+        poison = (fill(ckv, nan8), fill(kr, nan8), fill(cs, jnp.nan),
+                  fill(ks, jnp.nan))
+        kwargs = dict(scale=0.11)
+        out = _interpret_mla(q + poison + rest, kwargs)
+        assert np.isfinite(out).all()
+        _assert_bitwise(out, _interpret_mla(q + zeros + rest, kwargs))
+        _assert_bitwise(out, np.asarray(_full_walk(*q, *zeros, *rest,
+                                                   **kwargs)))
+        if case == "negative":
+            assert (out[0] == 0).all()
 
 
 # ---------------------------------------------------------------------------

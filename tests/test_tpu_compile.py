@@ -109,3 +109,31 @@ def test_paged_mla_kernel_carries_its_registry_name(one_chip):
     names = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
                        r"\"tpu_custom_call\"", text)
     assert [re.sub(r"\.\d+$", "", n) for n in names] == ["paged_mla_decode"]
+
+
+# argument bytes of the full page walk (no live-page rule) at the batch
+# cell's shape, compiled for the same described v5e
+FULL_WALK_ARG_BYTES = 421_241_344
+
+
+def test_paged_mla_kernel_at_the_batch_cell_shape(one_chip):
+    """The live-page rule compiles at the shape the batch cell runs (128
+    lanes, 80 pages of 64, fp8) as one kernel, and takes no argument
+    bytes beyond the full walk's: ``last`` comes from ``qpos``, which the
+    kernel already prefetches."""
+    out = {}
+
+    def args_of(key):
+        args, kwargs = chip_cases.paged_mla_args(key, page=64, storage="fp8",
+                                                 B=128, T=80 * 64)
+        out["kwargs"] = kwargs
+        return args
+
+    args = jax.eval_shape(args_of, jax.random.PRNGKey(0))
+    assert args[6].shape == (128, 80)
+    compiled = _compile("paged_mla_decode", args, out["kwargs"], one_chip)
+    names = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", compiled.as_text())
+    assert [re.sub(r"\.\d+$", "", n) for n in names] == ["paged_mla_decode"]
+    used = compiled.memory_analysis().argument_size_in_bytes
+    assert used <= FULL_WALK_ARG_BYTES, used

@@ -113,6 +113,33 @@ def test_decode_counters_count_lanes_and_emitted_tokens(dsv3_cfg):
     assert 0 < s["decode_tokens"] <= s["decode_lane_steps"]
 
 
+def test_live_page_counters_follow_each_lanes_position(dsv3_cfg):
+    """One decode chunk of 4 steps on 2 slots of 8 pages of 8: lane 0
+    (prompt 5) decodes at 5, 6, 7, 8, so 1, 1, 1, 2 live pages; lane 1
+    (prompt 13, one token left after its first) decodes at 13, then
+    stays at 14: 2 pages each step."""
+    eng = _paged(dsv3_cfg, attn_impl="pallas")
+    for i, (n, new) in enumerate([(5, 10), (13, 2)]):
+        eng.submit(Request(i, (np.arange(n) * (i + 3)) % eng.cfg.vocab_size,
+                           max_new=new))
+    eng.step()
+    assert eng.stats["decode_dispatches"] == 1
+    assert eng.stats["decode_live_pages"] == (1 + 1 + 1 + 2) + 4 * 2
+    assert eng.stats["decode_grid_pages"] == 2 * 8 * 4
+
+
+@pytest.mark.parametrize("kw", [dict(paged=False), dict(paged=True)],
+                         ids=["dense_pallas", "paged_xla"])
+def test_live_page_counters_stay_zero_off_the_kernel(dsv3_cfg, kw):
+    impl = "pallas" if not kw["paged"] else ""
+    eng = ServeEngine(dsv3_cfg, slots=2, max_len=64, seed=0, chunk=4,
+                      attn_impl=impl, **kw)
+    _serve(eng, [5, 13], max_new=3)
+    assert eng.stats["decode_dispatches"] > 0
+    assert eng.stats["decode_live_pages"] == 0
+    assert eng.stats["decode_grid_pages"] == 0
+
+
 def test_decode_chunk_hlo_carries_the_device_scopes(dsv3_cfg):
     cfg = dataclasses.replace(dsv3_cfg, fp8=True, fp8_impl="ref")
     eng = _paged(cfg)
