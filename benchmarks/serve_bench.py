@@ -313,7 +313,7 @@ def bench_kv_tier(arch: str = "qwen3-14b", *, requests: int = 14,
     import jax
     from repro.serve.engine import ServeEngine
     from repro.serve.fault import ServeFaultInjector, TierFaultAdapter
-    from repro.serve.tier import TierConfig
+    from repro.serve.tier import TierConfig, TieredServeEngine
 
     cfg = _smoke_cfg(arch)
 
@@ -342,13 +342,13 @@ def bench_kv_tier(arch: str = "qwen3-14b", *, requests: int = 14,
     stream_untiered, _ = run_stream(base)
 
     def tiered_engine(faults=None):
-        return ServeEngine(cfg, params=base.params, slots=slots,
-                           max_len=max_len, chunk=chunk, paged=True,
-                           page_size=PAGE_SIZE, pool_pages=TIER_POOL_PAGES,
-                           page_storage="bf16", prefill_chunk=prefill_chunk,
-                           host_tier_pages=TIER_HOST_PAGES,
-                           tier_config=TierConfig(quantum=TIER_QUANTUM),
-                           tier_faults=faults)
+        return TieredServeEngine(
+            cfg, params=base.params, slots=slots, max_len=max_len,
+            chunk=chunk, paged=True, page_size=PAGE_SIZE,
+            pool_pages=TIER_POOL_PAGES, page_storage="bf16",
+            prefill_chunk=prefill_chunk, host_tier_pages=TIER_HOST_PAGES,
+            tier_config=TierConfig(quantum=TIER_QUANTUM),
+            tier_faults=faults)
 
     eng = tiered_engine()
     stream, wall = run_stream(eng)

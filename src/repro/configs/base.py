@@ -138,9 +138,6 @@ class ModelConfig:
                                    # ("float8_e4m3fn" = paper §3.1 storage,
                                    # halves the decode weight wall)
     fp8: bool = False              # FP8-path GEMMs (paper T4)
-    fp8_impl: str = "ref"          # ref (inline jnp) | pallas (dispatch via
-                                   # repro.kernels.registry; actual backend
-                                   # picked by platform/env/use_backend)
 
     # notes for DESIGN/EXPERIMENTS provenance
     source: str = ""

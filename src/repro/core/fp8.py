@@ -14,16 +14,11 @@ and communication byte counts are genuine. Compute upcasts tiles to fp32 —
 on TPU the MXU runs bf16/fp32; the byte savings (HBM + ICI) are where FP8
 wins on this hardware, as laid out in DESIGN.md §2.
 
-``impl='pallas'`` routes the GEMM through the ``fp8_gemm`` kernel op in
-``repro.kernels.registry`` — which backend actually runs (TPU Pallas,
-CPU interpreter, or the jnp oracle) is the registry's backend policy
-(platform auto-detect / ``REPRO_KERNEL_BACKEND`` / ``kernels.use_backend``),
-never a caller kwarg. ``impl='ref'`` keeps the GEMM inline in jnp (the
-training path: both backward GEMMs quantize via ``scaled_matmul_ref``).
+The GEMM runs inline in jnp (``scaled_matmul_ref``), forward and both
+backward GEMMs alike.
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -166,36 +161,29 @@ def prequantized_linear(x: jax.Array, w: BlockQuantized) -> jax.Array:
     return scaled_matmul_ref(xq, xs, w.q, w.scale).astype(x.dtype)
 
 
-def _matmul_qdq(x: jax.Array, w: jax.Array, impl: str) -> jax.Array:
+def _matmul_qdq(x: jax.Array, w: jax.Array) -> jax.Array:
     """y = Q(x) @ Q(w) with fine-grained scales, fp32 accum."""
-    if impl == "pallas":
-        # registry-dispatched kernel op; backend (pallas/interpret/ref)
-        # resolved by repro.kernels.registry, not here
-        from repro.kernels.fp8_gemm import ops as fp8_ops
-        shape = x.shape
-        y = fp8_ops.fp8_matmul(x.reshape(-1, shape[-1]), w)
-        return y.reshape(shape[:-1] + (w.shape[-1],))
     with jax.named_scope("fp8_quant"):
         xq, xs = quantize_tilewise(x)
         wq, ws = quantize_blockwise(w)
     return scaled_matmul_ref(xq, xs, wq, ws)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def fp8_linear(x: jax.Array, w: jax.Array, impl: str = "ref") -> jax.Array:
+@jax.custom_vjp
+def fp8_linear(x: jax.Array, w: jax.Array) -> jax.Array:
     """FP8-path linear: fwd and both bwd GEMMs run quantized (paper recipe).
 
     x: (..., d) bf16/f32, w: (d, f). Returns (..., f) in x.dtype.
     """
-    return _matmul_qdq(x, w, impl).astype(x.dtype)
+    return _matmul_qdq(x, w).astype(x.dtype)
 
 
-def _fp8_linear_fwd(x, w, impl):
-    y = _matmul_qdq(x, w, impl).astype(x.dtype)
+def _fp8_linear_fwd(x, w):
+    y = _matmul_qdq(x, w).astype(x.dtype)
     return y, (x, w)
 
 
-def _fp8_linear_bwd(impl, res, g):
+def _fp8_linear_bwd(res, g):
     x, w = res
     gf = g.astype(jnp.float32)
     # dx = Q(g) @ Q(w^T): tile-quantize g along f, block-quantize w

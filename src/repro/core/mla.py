@@ -226,6 +226,14 @@ def mla_decode_step(p: dict, cache: dict, x: jax.Array, *,
     return _absorbed_out(p, o_lat, x, cfg), new_cache
 
 
+def paged_kernel_decode(impl: str, S: int = 1) -> bool:
+    """Whether paged attention over ``S`` query positions runs the
+    ``paged_mla_decode`` kernel: the ``pallas`` impl, one position.
+    Chunked prefill (``S > 1``) and the ``xla`` impl take the XLA
+    gather."""
+    return impl == "pallas" and S == 1
+
+
 def mla_paged_decode_step(p: dict, cache: dict, x: jax.Array, *,
                           cfg: ModelConfig, positions: jax.Array,
                           page_table: jax.Array,
@@ -276,7 +284,7 @@ def mla_paged_decode_step(p: dict, cache: dict, x: jax.Array, *,
     q_abs = _absorb_queries(p, q_nope, cfg)
     scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
 
-    if impl == "pallas" and S == 1:
+    if paged_kernel_decode(impl, S):
         from repro.kernels.paged_attention import ops as paged_ops
         ones = jnp.ones(cache["ckv"].shape[:2], jnp.float32)
         ckv_p, kr_p = new_cache["ckv"], new_cache["kr"]

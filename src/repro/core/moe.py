@@ -77,11 +77,6 @@ def expert_ffn(xbuf: jax.Array, w1, w3, w2, cfg: ModelConfig) -> jax.Array:
         w1, w3, w2 = (w.astype(dt0) for w in (w1, w3, w2))
     a = act_fn(cfg.act)
     dt = xbuf.dtype
-    if cfg.fp8_impl == "pallas":
-        # registry-dispatched kernel op (backend per repro.kernels.registry)
-        from repro.kernels.moe_gemm import ops as moe_ops
-        h = a(moe_ops.grouped_matmul(xbuf, w1)) * moe_ops.grouped_matmul(xbuf, w3)
-        return moe_ops.grouped_matmul(h.astype(dt), w2).astype(dt)
     g = jnp.einsum("ecd,edf->ecf", xbuf, w1.astype(dt))
     u = jnp.einsum("ecd,edf->ecf", xbuf, w3.astype(dt))
     h = a(g) * u

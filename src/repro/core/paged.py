@@ -34,9 +34,8 @@ einsums), which the parity tests pin.
 """
 from __future__ import annotations
 
-import zlib
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -424,230 +423,3 @@ class PrefixPageAllocator:
             self.refs[pid] = 1
             out.append((pid, key))
         return out
-
-
-# ---------------------------------------------------------------------------
-# Host-memory page tier (ROADMAP item 4 / Ma & Patterson memory hierarchy)
-# ---------------------------------------------------------------------------
-
-# Residency states of a tier entry. A page set starts on DEVICE (no entry),
-# enters SPILLING when a host reservation is made and the device->host
-# transfer is in flight, becomes HOST once the bytes are durable, and
-# FETCHING while a host->device transfer is in flight; a completed fetch
-# frees the entry (back to DEVICE). Transitions outside this cycle raise.
-TIER_SPILLING = "spilling"
-TIER_HOST = "host"
-TIER_FETCHING = "fetching"
-
-_TIER_TRANSITIONS = {
-    (TIER_SPILLING, TIER_HOST),     # commit
-    (TIER_HOST, TIER_FETCHING),     # begin_fetch
-    (TIER_FETCHING, TIER_HOST),     # abort_fetch (retry / preempted fetch)
-}
-
-
-def payload_page_crcs(payload: Any, n_pages: int) -> List[int]:
-    """CRC32 per page over a gathered page payload.
-
-    ``payload`` is a pytree of host numpy arrays whose axis 1 is the page
-    axis (``(layers, n_pages, page, ...)`` — the shape ``gather_pages``
-    hands back). Each page's checksum folds that page's bytes from every
-    leaf in deterministic pytree order, so a single flipped byte anywhere
-    in a spilled page is caught at fetch time.
-    """
-    crcs = [0] * n_pages
-    for leaf in jax.tree.leaves(payload):
-        a = np.asarray(leaf)
-        for j in range(n_pages):
-            crcs[j] = zlib.crc32(np.ascontiguousarray(a[:, j]).tobytes(),
-                                 crcs[j])
-    return crcs
-
-
-def payload_crc(payload: Any) -> int:
-    """Single CRC32 over a whole pytree of host arrays (aux leaves)."""
-    crc = 0
-    for leaf in jax.tree.leaves(payload):
-        crc = zlib.crc32(np.ascontiguousarray(np.asarray(leaf)).tobytes(),
-                         crc)
-    return crc
-
-
-def payload_nbytes(payload: Any) -> int:
-    """Total byte size of a pytree of host arrays (transfer accounting)."""
-    return sum(np.asarray(leaf).nbytes for leaf in jax.tree.leaves(payload))
-
-
-class TierEntry:
-    """One suspended slot's page set parked in (or moving through) the
-    host tier. Payloads are opaque pytrees of host numpy arrays; the tier
-    validates residency transitions and capacity, nothing else."""
-
-    __slots__ = ("eid", "n_pages", "state", "payload", "aux", "crcs",
-                 "aux_crc")
-
-    def __init__(self, eid: int, n_pages: int):
-        self.eid = eid
-        self.n_pages = n_pages
-        self.state = TIER_SPILLING
-        self.payload: Any = None
-        self.aux: Any = None
-        self.crcs: List[int] = []
-        self.aux_crc: int = 0
-
-
-class HostPageTier:
-    """Host-side (numpy) page store behind the device pool.
-
-    Capacity is counted in pages. Two kinds of content share it:
-
-    * **Slot entries** — a suspended request's whole page set plus its
-      decode aux leaves, reserved atomically via :meth:`reserve` and
-      tracked through the SPILLING -> HOST -> FETCHING state machine.
-    * **Prefix pages** — individual refcount-0 warm-LRU pages harvested
-      from the device allocator's prefix cache, one page each, kept in
-      their own LRU. They are cache copies, not the only copy, so they are
-      always evictable: a slot reservation squeezes the oldest prefix
-      pages out first.
-
-    Every spilled page carries a CRC32 (:func:`payload_page_crcs`) checked
-    at fetch time; the tier itself never touches a device buffer — staging
-    device<->host is the caller's job (``serve/tier.py`` helpers).
-    """
-
-    def __init__(self, capacity_pages: int):
-        if capacity_pages <= 0:
-            raise ValueError(f"host tier needs capacity > 0, "
-                             f"got {capacity_pages}")
-        self.capacity_pages = capacity_pages
-        self._entries: Dict[int, TierEntry] = {}
-        self._next_eid = 0
-        # key -> (payload, crc); insertion order is LRU order
-        self._prefix: "OrderedDict[bytes, Tuple[Any, int]]" = OrderedDict()
-        self.prefix_evictions = 0
-
-    # -- capacity ----------------------------------------------------------
-
-    def slot_pages(self) -> int:
-        return sum(e.n_pages for e in self._entries.values())
-
-    def prefix_pages(self) -> int:
-        return len(self._prefix)
-
-    def used_pages(self) -> int:
-        return self.slot_pages() + self.prefix_pages()
-
-    def free_pages(self) -> int:
-        return self.capacity_pages - self.used_pages()
-
-    def occupancy(self) -> float:
-        return self.used_pages() / self.capacity_pages
-
-    def entries(self) -> int:
-        return len(self._entries)
-
-    # -- slot entries ------------------------------------------------------
-
-    def reserve(self, n_pages: int) -> Optional[int]:
-        """Reserve ``n_pages`` for a suspending slot; returns an entry id
-        (state SPILLING) or None when the tier cannot fit it. Oldest
-        prefix pages are evicted to make room — they are cache copies and
-        a suspension is the only copy."""
-        if n_pages > self.capacity_pages:
-            return None
-        while self.free_pages() < n_pages and self._prefix:
-            self._prefix.popitem(last=False)
-            self.prefix_evictions += 1
-        if self.free_pages() < n_pages:
-            return None
-        eid = self._next_eid
-        self._next_eid += 1
-        self._entries[eid] = TierEntry(eid, n_pages)
-        return eid
-
-    def _entry(self, eid: int, *states: str) -> TierEntry:
-        e = self._entries.get(eid)
-        if e is None:
-            raise KeyError(f"tier entry {eid} does not exist")
-        if states and e.state not in states:
-            raise ValueError(f"tier entry {eid} is {e.state}, "
-                             f"expected one of {states}")
-        return e
-
-    def _transition(self, e: TierEntry, to: str) -> None:
-        if (e.state, to) not in _TIER_TRANSITIONS:
-            raise ValueError(f"illegal tier transition {e.state} -> {to} "
-                             f"for entry {e.eid}")
-        e.state = to
-
-    def commit(self, eid: int, payload: Any, aux: Any,
-               crcs: Sequence[int], aux_crc: int) -> None:
-        """Land a spill: SPILLING -> HOST with the page bytes durable."""
-        e = self._entry(eid, TIER_SPILLING)
-        if len(crcs) != e.n_pages:
-            raise ValueError(f"entry {eid}: {len(crcs)} CRCs for "
-                             f"{e.n_pages} pages")
-        self._transition(e, TIER_HOST)
-        e.payload, e.aux, e.crcs, e.aux_crc = payload, aux, list(crcs), aux_crc
-
-    def begin_fetch(self, eid: int) -> TierEntry:
-        """HOST -> FETCHING; returns the entry (payload/crcs readable)."""
-        e = self._entry(eid, TIER_HOST)
-        self._transition(e, TIER_FETCHING)
-        return e
-
-    def abort_fetch(self, eid: int) -> None:
-        """FETCHING -> HOST (failed/preempted fetch keeps the host copy)."""
-        e = self._entry(eid, TIER_FETCHING)
-        self._transition(e, TIER_HOST)
-
-    def state(self, eid: int) -> str:
-        return self._entry(eid).state
-
-    def free(self, eid: int) -> None:
-        """Drop an entry in any state (fetch completed, cancel, degrade)."""
-        self._entry(eid)
-        del self._entries[eid]
-
-    # -- prefix page cache -------------------------------------------------
-
-    def put_prefix(self, key: bytes, payload: Any, crc: int) -> bool:
-        """Park one harvested prefix page under ``key``. Evicts older
-        prefix pages LRU to fit, never slot entries; returns False when
-        slot entries alone leave no room."""
-        if key in self._prefix:
-            self._prefix.move_to_end(key)
-            return True
-        while self.free_pages() < 1 and self._prefix:
-            self._prefix.popitem(last=False)
-            self.prefix_evictions += 1
-        if self.free_pages() < 1:
-            return False
-        self._prefix[key] = (payload, crc)
-        return True
-
-    def prefix_run(self, keys: Sequence[bytes], granularity: int = 1) -> int:
-        """Length (pages, rounded down to ``granularity``) of the leading
-        contiguous run of ``keys`` present in the prefix cache."""
-        n = 0
-        for key in keys:
-            if key not in self._prefix:
-                break
-            n += 1
-        return n // granularity * granularity
-
-    def take_prefix(self, keys: Sequence[bytes]
-                    ) -> List[Tuple[Any, int]]:
-        """Read ``(payload, crc)`` per key (all must be present), touching
-        each entry to MRU. Entries stay cached — a fetch copies them back
-        to the device, it does not remove the host copy."""
-        out = []
-        for key in keys:
-            if key not in self._prefix:
-                raise KeyError("prefix page vanished from the tier")
-            self._prefix.move_to_end(key)
-            out.append(self._prefix[key])
-        return out
-
-    def drop_prefix(self, key: bytes) -> None:
-        self._prefix.pop(key, None)

@@ -52,6 +52,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -93,6 +94,13 @@ def _last_live_page(qpos, page: int, pp: int):
     """A slot's last live page: ``max(qpos, 0) // page``, capped at
     ``pp - 1``. Every row of a later page lies above ``qpos``."""
     return jnp.minimum(jax.lax.div(jnp.maximum(qpos, 0), page), pp - 1)
+
+
+def live_pages(qpos: np.ndarray, page: int, pp: int) -> np.ndarray:
+    """How many pages ``paged_mla_decode`` fetches and computes for each
+    query position in ``qpos`` (host numpy, any shape): the pages up to
+    :func:`_last_live_page`, the kernel's own rule."""
+    return np.minimum(np.maximum(qpos, 0) // page, pp - 1) + 1
 
 
 def _kernel(table_ref, qpos_ref, qa_ref, qr_ref, ckv_ref, kr_ref,

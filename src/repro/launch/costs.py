@@ -34,10 +34,6 @@ from typing import Dict
 
 from repro.configs.base import ModelConfig, ShapeCfg, get_config
 
-# hardware constants (assignment-fixed, TPU v5e)
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # B/s / chip
-ICI_BW = 50e9                # B/s / link
 BYTES_PARAM = 2              # bf16 weights on the wire/HBM
 
 

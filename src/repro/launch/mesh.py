@@ -1,12 +1,9 @@
-"""Production mesh construction (assignment-fixed shapes).
+"""Mesh construction over the (pod, data, model) axes.
 
-Single pod: (16, 16) = 256 chips, axes (data, model).
-Multi-pod:  (2, 16, 16) = 512 chips, axes (pod, data, model) — the pod
-axis is the scarce DCN/optical fabric (the paper's IB analogue); EP
-all-to-all is confined to intra-pod axes by construction (DESIGN.md §5).
-
-Defined as functions so importing this module never touches jax device
-state (device count is locked at first backend init). Meshes use
+The pod axis is the scarce DCN/optical fabric (the paper's IB analogue);
+EP all-to-all is confined to intra-pod axes by construction (DESIGN.md
+§5). Defined as functions so importing this module never touches jax
+device state (device count is locked at first backend init). Meshes use
 ``Auto`` axes: GSPMD propagates the shardings the rules declare.
 """
 from __future__ import annotations
@@ -19,16 +16,6 @@ def make_mesh(shape, axes) -> Mesh:
     """``jax.make_mesh`` over ``Auto`` axes (its default is Explicit)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def dp_axes_for(mesh: Mesh):
-    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
 def survivor_mesh(mesh: Mesh) -> Mesh:

@@ -111,13 +111,14 @@ def main():
     paged_kw = dict(paged=args.paged, page_size=args.page_size,
                     pool_pages=args.pool_pages,
                     page_storage=args.page_storage)
+    tier_kw = {}
     if args.host_tier_pages is not None:
         if not args.paged:
             raise SystemExit("--host-tier-pages requires --paged")
         if args.disagg:
             raise SystemExit("--host-tier-pages does not apply to the "
                              "--disagg decode pool yet")
-        paged_kw["host_tier_pages"] = args.host_tier_pages
+        tier_kw["host_tier_pages"] = args.host_tier_pages
     from repro.launch import compile_cache
     compile_cache.enable()
     ctx = _make_ctx(args.mesh, args.moe_impl, args.wire)
@@ -128,6 +129,7 @@ def main():
     from repro.serve.disagg import Disaggregator
     from repro.serve.engine import Request, ServeEngine
     from repro.serve.speculative import measured
+    from repro.serve.tier import TieredServeEngine
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -152,7 +154,7 @@ def main():
                      max_len=args.max_len, chunk=args.chunk,
                      temperature=args.temperature, top_k=args.top_k,
                      max_retries=args.max_retries, injector=injector,
-                     **paged_kw)
+                     **paged_kw, **tier_kw)
         grs = [gw.submit((np.arange(5 + i * 2) * (i + 3)) % cfg.vocab_size,
                          max_new=args.max_new)
                for i in range(args.requests)]
@@ -207,10 +209,11 @@ def main():
         prefill_eng = eng.prefill_pool
         eng = eng.decode
     else:
-        eng = ServeEngine(cfg, slots=args.slots, max_len=args.max_len,
-                          use_mtp=args.mtp, chunk=args.chunk,
-                          temperature=args.temperature, top_k=args.top_k,
-                          ctx=ctx, **paged_kw)
+        engine_cls = TieredServeEngine if tier_kw else ServeEngine
+        eng = engine_cls(cfg, slots=args.slots, max_len=args.max_len,
+                         use_mtp=args.mtp, chunk=args.chunk,
+                         temperature=args.temperature, top_k=args.top_k,
+                         ctx=ctx, **paged_kw, **tier_kw)
         for r in reqs:
             eng.submit(r)
         eng.run_until_done()

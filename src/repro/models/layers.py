@@ -36,15 +36,13 @@ def linear(x: jax.Array, w: Union[jax.Array, fp8.BlockQuantized],
            cfg: Optional[ModelConfig] = None,
            b: Optional[jax.Array] = None) -> jax.Array:
     """Dense GEMM; routes through the FP8 fine-grained-scaled path (paper
-    T4) when the config enables it. With ``cfg.fp8_impl='pallas'`` the
-    GEMM dispatches through the kernel registry (``repro.kernels``) —
-    backend selection lives there, not in layer code. A weight already
-    held as ``fp8.BlockQuantized`` (serving, see ``fp8_weights``)
-    skips its per-call quantization; the GEMM's operands are unchanged."""
+    T4) when the config enables it. A weight already held as
+    ``fp8.BlockQuantized`` (serving, see ``fp8_weights``) skips its
+    per-call quantization; the GEMM's operands are unchanged."""
     if fp8.is_quantized(w):
         y = fp8.prequantized_linear(x, w)
     elif cfg is not None and cfg.fp8 and w.ndim == 2 and x.shape[-1] >= 256:
-        y = fp8.fp8_linear(x, w, impl=cfg.fp8_impl)
+        y = fp8.fp8_linear(x, w)
     else:
         y = jnp.einsum("...d,df->...f", x, w.astype(x.dtype))
     if b is not None:
@@ -63,11 +61,10 @@ _LINEAR_WEIGHTS = {
 
 
 def fp8_weights(params, cfg: ModelConfig) -> Dict[str, jax.Array]:
-    """The bf16 weight stacks that ``linear`` sends to ``fp8_linear``'s
-    jnp path, by ``keystr`` path: those a server may hold quantized once.
-    The Pallas impl quantizes inside its kernel op, so it gets none."""
+    """The bf16 weight stacks that ``linear`` sends to ``fp8_linear``,
+    by ``keystr`` path: those a server may hold quantized once."""
     out: Dict[str, jax.Array] = {}
-    if not (cfg.fp8 and cfg.fp8_impl == "ref"):
+    if not cfg.fp8:
         return out
 
     def visit(path, leaf):

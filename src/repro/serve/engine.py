@@ -61,11 +61,12 @@ from jax.profiler import TraceAnnotation as _span
 
 from repro.configs.base import ModelConfig
 from repro.core import fp8 as fp8_mod
+from repro.core import mla as mla_mod
 from repro.core import paged as paged_mod
+from repro.kernels.paged_attention.paged_attention import live_pages
 from repro.models import layers as Lyr
 from repro.models.api import Model, build_model
 from repro.parallel import context as pctx_mod
-from repro.serve import tier as tier_mod
 
 # Smallest prefill bucket: prompts shorter than this share one compile.
 MIN_BUCKET = 8
@@ -148,20 +149,6 @@ def _splice(batch_cache, one_cache, slot, axes):
     return jax.tree.map(f, batch_cache, one_cache, axes)
 
 
-def _slot_slice(batch_cache, slot, axes):
-    """Read slot ``slot`` out of a batch cache as a batch-1 pytree — the
-    inverse of :func:`_splice` for full-length leaves (``slot`` traced).
-    Used by the tier's suspension gather to capture a slot's aux leaves
-    (encoder memory, MTP state) alongside its pages."""
-    def f(big, ax):
-        starts = tuple(slot if i == ax else 0 for i in range(big.ndim))
-        sizes = tuple(1 if i == ax else big.shape[i]
-                      for i in range(big.ndim))
-        return jax.lax.dynamic_slice(big, starts, sizes)
-
-    return jax.tree.map(f, batch_cache, axes)
-
-
 class ServeEngine:
     """Fixed-slot batch engine (continuous batching-lite).
 
@@ -182,9 +169,6 @@ class ServeEngine:
                  page_storage: str = "fp8",
                  max_pending: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
-                 host_tier_pages: Optional[int] = None,
-                 tier_config: Optional[tier_mod.TierConfig] = None,
-                 tier_faults=None,
                  attn_impl: str = "",
                  decode_overlap: bool = False,
                  ctx: Optional[pctx_mod.ParallelCtx] = None):
@@ -199,8 +183,8 @@ class ServeEngine:
                                    "mla_impl": attn_impl}
         self.attn_impl = attn_impl
         # paged decode of MLA layers runs the paged_mla_decode kernel
-        self._mla_kernel_decode = (paged and attn_impl == "pallas"
-                                   and cfg.attention == "mla")
+        self._mla_kernel_decode = (paged and cfg.attention == "mla"
+                                   and mla_mod.paged_kernel_decode(attn_impl))
         self.ctx = ctx
         self.meshed = ctx is not None and ctx.mesh is not None
         self._param_shardings = None
@@ -276,38 +260,6 @@ class ServeEngine:
             self._aux_axes = self.model.paged_aux_axes()
         else:
             self.cache = self.model.init_cache(slots, max_len)
-        # host-memory KV page tier (ROADMAP 4): the device pool becomes a
-        # cache over `host_tier_pages` of host capacity — suspended slots
-        # spill whole page sets, warm refcount-0 prefix pages spill ahead
-        # of reuse, and everything rides the staged §4.5 host hop on the
-        # tick-clocked transfer model in serve/tier.py
-        self.tier: Optional[paged_mod.HostPageTier] = None
-        if host_tier_pages is not None:
-            if not paged:
-                raise ValueError("host_tier_pages requires paged=True: the "
-                                 "tier spills page sets, dense rings have "
-                                 "none")
-            self.tier = paged_mod.HostPageTier(host_tier_pages)
-        elif tier_faults is not None:
-            raise ValueError("tier_faults without host_tier_pages: there "
-                             "is no tier transfer path to inject into")
-        self.tier_cfg = (tier_config if tier_config is not None
-                         else tier_mod.TierConfig())
-        self.tier_faults = (tier_faults if tier_faults is not None
-                            else tier_mod.NullFaultHook())
-        self._xfers = tier_mod.TransferClock(self.tier_cfg)
-        # rid -> suspension entry; insertion order is the resume order
-        self._suspended: "collections.OrderedDict[int, Dict[str, Any]]" = \
-            collections.OrderedDict()
-        self._spilling_slots: Dict[int, int] = {}   # slot -> rid
-        self._slot_tick0 = np.zeros((slots,), np.int64)
-        self._tick = 0
-        self.tstats = {"suspensions": 0, "resumes": 0, "spilled_pages": 0,
-                       "fetched_pages": 0, "spill_bytes": 0,
-                       "fetch_bytes": 0, "prefetch_stalls": 0,
-                       "degraded": 0, "crc_failures": 0, "spill_aborts": 0,
-                       "tier_full_refusals": 0, "peak_resident_pages": 0,
-                       "prefix_spilled": 0, "prefix_fetched": 0}
         self._cache_shardings = None
         self._state_shardings = None
         self._tok_sharding = None
@@ -366,9 +318,6 @@ class ServeEngine:
         self._release_traces = 0
         self._chunk_traces = 0
         self._table_traces = 0
-        self._tier_gather_traces = 0
-        self._tier_scatter_traces = 0
-        self._tier_resume_traces = 0
         # meshed engines pin the cache/state out-shardings to the input
         # shardings: without the pin, GSPMD could hand back a re-sharded
         # cache and the next dispatch would see new input shardings and
@@ -434,65 +383,6 @@ class ServeEngine:
                     table_install, donate_argnums=(0,),
                     out_shardings=cache_out)
 
-            if self.tier is not None:
-                # the three tier entry points, each compile-once: gather
-                # reads a fixed pages_per_slot-wide id vector (trash-padded)
-                # plus the slot's aux leaves; scatter installs a payload of
-                # the same static width (no page-table change — FETCHING
-                # pages hold bytes before any row references them); resume
-                # installs the table row + aux splice when a slot frees up
-                def tier_gather(cache, ids, slot):
-                    self._tier_gather_traces += 1
-                    pages = self.model.gather_pages(cache, ids)
-                    aux = {}
-                    if self._aux_axes:
-                        aux = _slot_slice(
-                            {k: cache[k] for k in self._aux_axes}, slot,
-                            self._aux_axes)
-                    return pages, aux
-
-                gather_out = None
-                if self.meshed:
-                    from jax.sharding import NamedSharding, PartitionSpec
-                    from repro.parallel import sharding as sh_mod
-                    zids = np.zeros((self.pages_per_slot,), np.int32)
-                    pay_s, aux_s = jax.eval_shape(tier_gather, self.cache,
-                                                  zids, 0)
-                    self._tier_gather_traces = 0   # eval_shape traced once
-                    rep = NamedSharding(self.ctx.mesh, PartitionSpec())
-                    gather_out = (
-                        sh_mod.tier_payload_pspecs(
-                            pay_s, self.ctx.mesh,
-                            self.ctx.tp_axis or "model"),
-                        jax.tree.map(lambda _: rep, aux_s))
-                # repro-lint: disable=R2-jit-contract -- the cache is
-                # only read, never donated: the suspended slot's gather
-                # must keep decoding peers' pool buffer alive
-                self._tier_gather_fn = jax.jit(tier_gather,
-                                               out_shardings=gather_out)
-
-                def tier_scatter(cache, pages, ids):
-                    self._tier_scatter_traces += 1
-                    return self.model.install_pages(cache, pages, ids)
-
-                self._tier_scatter_fn = jax.jit(
-                    tier_scatter, donate_argnums=(0,),
-                    out_shardings=cache_out)
-
-                def tier_resume(cache, aux, row, slot):
-                    self._tier_resume_traces += 1
-                    table = cache["page_table"]
-                    out = dict(cache)
-                    out["page_table"] = jax.lax.dynamic_update_slice(
-                        table, row[None].astype(table.dtype), (slot, 0))
-                    if aux:
-                        big = {k: out[k] for k in aux}
-                        out.update(_splice(big, aux, slot, self._aux_axes))
-                    return out
-
-                self._tier_resume_fn = jax.jit(
-                    tier_resume, donate_argnums=(0,),
-                    out_shardings=cache_out)
         else:
             axes = self.model.cache_batch_axes(slots, max_len)
 
@@ -629,12 +519,7 @@ class ServeEngine:
                 "scatter": self._scatter_traces,
                 "release": self._release_traces,
                 "chunk": self._chunk_traces,
-                "table": self._table_traces,
-                # tier engines: gather/scatter/resume each ≤ 1 — every
-                # transfer pads to the static pages_per_slot width
-                "tier_gather": self._tier_gather_traces,
-                "tier_scatter": self._tier_scatter_traces,
-                "tier_resume": self._tier_resume_traces}
+                "table": self._table_traces}
 
     def decode_lowered_text(self) -> str:
         """StableHLO text of the fused decode chunk at this engine's
@@ -753,6 +638,12 @@ class ServeEngine:
                     req.sample_offset + len(req.out))
         return prompt, req.max_new, req.sample_offset
 
+    def _page_row(self, pages: List[int]) -> np.ndarray:
+        """``pages`` padded with the trash page to a table row's width."""
+        row = np.full((self.pages_per_slot,), self.pool_pages, np.int32)
+        row[:len(pages)] = pages
+        return row
+
     def pages_needed(self, req: Request) -> int:
         """Page budget a request reserves at admission: every position it
         can touch — prompt plus decode budget — rounded up to pages. The
@@ -857,13 +748,11 @@ class ServeEngine:
             if self.paged:
                 alloc = self._alloc.alloc(n)
                 self._slot_pages[slot] = alloc
-                trash = self.pool_pages
-                row = np.full((self.pages_per_slot,), trash, np.int32)
-                row[:n] = alloc
+                row = self._page_row(alloc)
                 # prefill pages beyond the reserved range (bucket > request
                 # budget) land in the trash page
                 n_p = jax.tree.leaves(cache1["pages"])[0].shape[1]
-                ids = np.asarray([alloc[i] if i < n else trash
+                ids = np.asarray([alloc[i] if i < n else self.pool_pages
                                   for i in range(n_p)], np.int32)
                 self.stats["page_admits"] += 1
                 used = self.pool_pages - self.free_pages()
@@ -888,7 +777,7 @@ class ServeEngine:
                                        # stream index offset
         self._slot_extras[slot] = extras
         self.active[slot] = req
-        self._slot_tick0[slot] = self._tick
+        self._on_decode_start(slot)
 
     # -- scheduler ----------------------------------------------------------
     def _admit_now(self, req: Request, extras: Optional[Dict]):
@@ -900,13 +789,14 @@ class ServeEngine:
             self.admit_prefilled(req, first, cache1, slot, extras=extras)
 
     def _admit_chunked(self, req: Request, extras: Optional[Dict],
-                       slot: int):
+                       slot: int) -> int:
         """Reserve pages (claiming any indexed prefix run) and install the
         slot's page-table row; the prompt itself streams through
         ``_run_prefill_chunk`` one chunk per ``step()``. Pages claimed
         from the prefix index are shared and immutable — the chunks that
         would have computed them are skipped, and fresh pages take over
-        from the divergence point (the copy-on-write fork)."""
+        from the divergence point (the copy-on-write fork). Returns how
+        many leading pages of ``_slot_pages[slot]`` the index supplied."""
         if extras:
             raise ValueError(
                 "prefill_chunk admission does not support extras "
@@ -932,9 +822,7 @@ class ServeEngine:
         pages = hits + fresh
         self._slot_pages[slot] = pages
         self._slot_extras[slot] = extras
-        trash = self.pool_pages
-        row = np.full((self.pages_per_slot,), trash, np.int32)
-        row[:n] = pages
+        row = self._page_row(pages)
         self.stats["page_admits"] += 1
         used = self.pool_pages - self.free_pages()
         self.stats["peak_pages_used"] = max(
@@ -953,39 +841,7 @@ class ServeEngine:
                                       prompt=prompt, max_new=max_new,
                                       offset=offset, row=row)
         self.active[slot] = req
-        self._slot_tick0[slot] = self._tick
-        if self.tier is not None:
-            self._probe_tier_prefix(slot, hits, fresh, keys, L, skip)
-
-    def _probe_tier_prefix(self, slot: int, hits: List[int],
-                           fresh: List[int], keys: List[bytes], L: int,
-                           skip: int):
-        """Extend a chunked admission's shared-prefix run with host-tier
-        prefix pages: pages past the device hit run that the tier holds
-        are fetched into the slot's fresh pages instead of recomputed.
-        The prefill cursor only advances when the fetch lands CRC-clean
-        (``_finish_prefix_fetch``); until then the slot waits — decode
-        never reads a page before its bytes are installed."""
-        p, C = self.page_size, self.prefill_chunk
-        ppc = C // p
-        h = len(hits)
-        if skip != h * p:
-            return   # device hits already reach the final-chunk bound
-        bound_pages = ((L - 1) // C * C) // p
-        run = min(self.tier.prefix_run(keys[h:], ppc),
-                  bound_pages - h) // ppc * ppc
-        if run <= 0:
-            return
-        tkeys = keys[h:h + run]
-        stored = self.tier.take_prefix(tkeys)
-        ps = self._prefilling[slot]
-        ps["tier_xfer"] = True
-        self._xfers.submit(
-            tier_mod.PREFIX_FETCH, ps["req"].rid, None,
-            sum(paged_mod.payload_nbytes(pg) for pg, _ in stored),
-            slow=self.tier_faults.slow(), slot=slot, req=ps["req"],
-            keys=tkeys, stored=stored, pages=fresh[:run],
-            end=(h + run) * p)
+        return len(hits)
 
     def _run_prefill_chunk(self, slot: int):
         """Advance one prefilling slot by one chunk; the final chunk
@@ -1049,7 +905,10 @@ class ServeEngine:
         self._eos[slot] = -1 if req.eos is None else req.eos
         self._rngs[slot] = np.asarray(base, np.uint32)
         self._tix[slot] = ps["offset"] + 1
-        self._slot_tick0[slot] = self._tick   # quantum clock: decode start
+        self._on_decode_start(slot)
+
+    def _on_decode_start(self, slot: int):
+        """``slot`` decodes from the next dispatch on (a subclass hook)."""
 
     def _pick_admission(self) -> Optional[int]:
         """Index of the pending entry to admit next: highest priority
@@ -1069,45 +928,31 @@ class ServeEngine:
                 return i
         return None
 
+    def _masked_slots(self) -> set:
+        """Occupied slots whose lanes the decode dispatch masks out: those
+        mid-chunked-prefill, which are not decoding yet."""
+        return set(self._prefilling)
+
+    def _eviction_victim(self, inc: int) -> Optional[int]:
+        """The lowest-priority decoding resident whose priority is
+        strictly below ``inc``, or None."""
+        masked = self._masked_slots()
+        victims = [(r.priority, s) for s, r in enumerate(self.active)
+                   if r is not None and s not in masked
+                   and r.priority < inc]
+        return min(victims)[1] if victims else None
+
     def _try_evict(self, inc: int) -> bool:
         """Free capacity for an incoming priority-``inc`` request: evict
         the lowest-priority resident whose priority is strictly lower,
-        or — when no resident qualifies — abort the fetch of a
-        strictly-lower-priority suspended entry (its host copy survives;
-        the fetch restarts later), or reclaim the retained prefix pages
-        of a strictly-lower-priority evicted continuation (it will
+        or — when no resident qualifies — reclaim the retained prefix
+        pages of a strictly-lower-priority evicted continuation (it will
         re-prefill; its token stream stays bitwise-identical either way).
-        Tiered engines prefer *spilling* the victim over evicting it —
-        its KV moves to the host instead of being recomputed — in which
-        case this returns False: the capacity arrives asynchronously when
-        the spill lands, and the caller must not keep preempting for the
-        same arrival this tick."""
-        victims = [(self.active[s].priority, s) for s in range(self.slots)
-                   if self.active[s] is not None
-                   and s not in self._prefilling
-                   and s not in self._spilling_slots
-                   and self.active[s].priority < inc]
-        if victims:
-            slot = min(victims)[1]
-            if self.tier is not None and self._begin_suspend(slot):
-                return False
+        Returns whether capacity was freed."""
+        slot = self._eviction_victim(inc)
+        if slot is not None:
             self._evict_slot(slot)
             return True
-        if self.tier is not None:
-            fetching = [(e["req"].priority, rid)
-                        for rid, e in self._suspended.items()
-                        if e["state"] == "fetching"
-                        and e["req"].priority < inc]
-            if fetching:
-                rid = min(fetching)[1]
-                e = self._suspended[rid]
-                self._xfers.cancel(lambda t: t.rid == rid
-                                   and t.kind == tier_mod.FETCH)
-                self.tier.abort_fetch(e["eid"])
-                self._alloc.release(e["fetch_pages"])
-                e["fetch_pages"], e["tier_entry"] = None, None
-                e["state"] = "host"
-                return True
         held = [(req.priority, i) for i, (req, _) in
                 enumerate(self.pending)
                 if req.priority < inc and req.rid in self._evicted]
@@ -1183,349 +1028,6 @@ class ServeEngine:
             admitted += 1
         return admitted
 
-    # -- host page tier (ISSUE 9 / §4.5 memory hierarchy) --------------------
-    def _begin_suspend(self, slot: int) -> bool:
-        """Start spilling ``slot``'s whole page set to the host tier.
-
-        The gather + staged copy happen eagerly (the slot's masked decode
-        lane would otherwise keep mutating aux state, and a reused slot
-        would overwrite it), the page-table row is trashed immediately so
-        no later dispatch can write into the captured pages, and the
-        *transfer clock* models when the host copy becomes durable — the
-        slot and its device pages stay held until the spill lands, so a
-        failed spill resumes in place with zero lost work. Returns False
-        when the tier cannot take the pages (caller falls back to the
-        PR 8 evict-and-requeue rung)."""
-        req = self.active[slot]
-        pages = self._slot_pages[slot]
-        n = len(pages)
-        if n == 0:
-            return False
-        if self.tier_faults.full():
-            self.tstats["tier_full_refusals"] += 1
-            return False
-        eid = self.tier.reserve(n)
-        if eid is None:
-            self.tstats["tier_full_refusals"] += 1
-            return False
-        trash = self.pool_pages
-        ids = np.asarray(pages + [trash] * (self.pages_per_slot - n),
-                         np.int32)
-        self.stats["dispatches"] += 1
-        pay_dev, aux_dev = self._tier_gather_fn(self.cache,
-                                                jnp.asarray(ids), slot)
-        payload = tier_mod.trim_pages(tier_mod.staged_get(pay_dev), n)
-        aux = tier_mod.staged_get(aux_dev)
-        crcs = paged_mod.payload_page_crcs(payload, n)
-        aux_crc = paged_mod.payload_crc(aux)
-        nbytes = (paged_mod.payload_nbytes(payload)
-                  + paged_mod.payload_nbytes(aux))
-        # trash the row now: the captured bytes must stay immutable while
-        # the transfer is in flight (the lane is masked out of decode, but
-        # masked lanes still write through their row)
-        self.stats["dispatches"] += 1
-        self.cache = self._release_fn(self.cache, slot)
-        mirrors = dict(pos=int(self.positions[slot]),
-                       tok=int(self._tokens[slot]),
-                       left=int(self._left[slot]),
-                       eos=int(self._eos[slot]),
-                       rng=self._rngs[slot].copy(),
-                       tix=int(self._tix[slot]))
-        self._xfers.submit(tier_mod.SPILL, req.rid, eid, nbytes,
-                           slow=self.tier_faults.slow())
-        self._suspended[req.rid] = dict(
-            req=req, extras=self._slot_extras[slot], state="spilling",
-            eid=eid, n=n, slot=slot, pages=None, fetch_pages=None,
-            tier_entry=None, payload=payload, aux=aux, crcs=crcs,
-            aux_crc=aux_crc, mirrors=mirrors)
-        self._spilling_slots[slot] = req.rid
-        self.tstats["suspensions"] += 1
-        return True
-
-    def _finish_spill(self, t: tier_mod.TierTransfer):
-        """A spill landed: the host copy is durable, so the device side —
-        slot and pages — finally frees (the row was trashed at suspend)."""
-        e = self._suspended.get(t.rid)
-        if e is None or e["state"] != "spilling":
-            return   # cancelled while in flight
-        self.tier.commit(e["eid"], e["payload"], e["aux"], e["crcs"],
-                         e["aux_crc"])
-        slot = e.pop("slot")
-        del self._spilling_slots[slot]
-        self._alloc.release(self._slot_pages[slot])
-        self._slot_pages[slot] = []
-        self.stats["page_releases"] += 1
-        self.active[slot] = None
-        self._slot_extras[slot] = None
-        e["state"] = "host"
-        e["payload"] = None   # the tier owns the bytes now
-        self.tstats["spilled_pages"] += e["n"]
-        self.tstats["spill_bytes"] += t.nbytes
-
-    def _fail_spill(self, t: tier_mod.TierTransfer):
-        """Spill transfer failed terminally: resume in place. The device
-        pages were never released, so re-installing the row + aux loses
-        nothing — the degradation ladder's cheapest rung."""
-        e = self._suspended.pop(t.rid, None)
-        if e is None:
-            return
-        self.tier.free(e["eid"])
-        slot = e["slot"]
-        del self._spilling_slots[slot]
-        self.tstats["spill_aborts"] += 1
-        pages = self._slot_pages[slot]
-        trash = self.pool_pages
-        row = np.full((self.pages_per_slot,), trash, np.int32)
-        row[:len(pages)] = pages
-        self.stats["dispatches"] += 1
-        self.cache = self._tier_resume_fn(self.cache, e["aux"],
-                                          jnp.asarray(row), slot)
-        self._restore_mirrors(slot, e["mirrors"])
-        self._slot_tick0[slot] = self._tick
-
-    def _restore_mirrors(self, slot: int, m: Dict[str, Any]):
-        self.positions[slot] = m["pos"]
-        self._tokens[slot] = m["tok"]
-        self._left[slot] = m["left"]
-        self._eos[slot] = m["eos"]
-        self._rngs[slot] = m["rng"]
-        self._tix[slot] = m["tix"]
-
-    def _start_fetches(self):
-        """Prefetch-ahead: start host->device transfers for suspended
-        entries, FIFO (oldest suspension first), using whatever pool pages
-        admission left over this tick. A page-blocked entry blocks the
-        ones behind it (no small-latecomer jumping — that is the
-        admission queue's starvation lesson applied here), and when the
-        pending head's starvation guard has tripped, freed pages are its
-        alone, so no fetch starts at all."""
-        if self.tier is None:
-            return
-        if self.pending and self._hol_skips >= STARVATION_LIMIT:
-            return
-        for rid, e in self._suspended.items():
-            if e["state"] != "host":
-                continue
-            n = e["n"]
-            if n > self.free_pages():
-                break
-            e["fetch_pages"] = self._alloc.alloc(n)
-            ent = self.tier.begin_fetch(e["eid"])
-            e["tier_entry"] = ent
-            e["state"] = "fetching"
-            nbytes = (paged_mod.payload_nbytes(ent.payload)
-                      + paged_mod.payload_nbytes(ent.aux))
-            self._xfers.submit(tier_mod.FETCH, rid, e["eid"], nbytes,
-                               slow=self.tier_faults.slow())
-
-    def _finish_fetch(self, t: tier_mod.TierTransfer):
-        """A fetch landed: CRC-check the host bytes, scatter them into the
-        reserved device pages, and mark the entry ready to resume the
-        moment a slot frees. CRC mismatch walks the degradation ladder."""
-        e = self._suspended.get(t.rid)
-        if e is None or e["state"] != "fetching":
-            return
-        ent, n = e["tier_entry"], e["n"]
-        if (paged_mod.payload_page_crcs(ent.payload, n) != ent.crcs
-                or paged_mod.payload_crc(ent.aux) != ent.aux_crc):
-            self.tstats["crc_failures"] += 1
-            self._degrade(t.rid)
-            return
-        pages = e["fetch_pages"]
-        trash = self.pool_pages
-        ids = np.asarray(pages + [trash] * (self.pages_per_slot - n),
-                         np.int32)
-        payload = tier_mod.pad_pages(ent.payload, self.pages_per_slot)
-        self.stats["dispatches"] += 1
-        self.cache = self._tier_scatter_fn(
-            self.cache, tier_mod.staged_put(payload), jnp.asarray(ids))
-        e["aux"] = ent.aux
-        e["pages"], e["fetch_pages"] = pages, None
-        e["tier_entry"] = None
-        e["state"] = "ready"
-        self.tier.free(e["eid"])
-        self.tstats["fetched_pages"] += n
-        self.tstats["fetch_bytes"] += t.nbytes
-
-    def _degrade(self, rid: int):
-        """Unrecoverable fetch (retries exhausted / timeout / CRC): drop
-        the tiered copy and re-queue the request as a PR 7-style
-        continuation — ``_effective`` re-prefills prompt+delivered at the
-        advanced stream offset, so a seeded request's completed stream
-        stays bitwise-identical to the no-fault run."""
-        e = self._suspended.pop(rid, None)
-        if e is None:
-            return
-        if e["fetch_pages"]:
-            self._alloc.release(e["fetch_pages"])
-        self.tier.free(e["eid"])
-        self.tstats["degraded"] += 1
-        self.pending.appendleft((e["req"], e["extras"]))
-
-    def _resume_ready(self) -> int:
-        """Re-admit fetched entries (suspension order) into free slots:
-        one jitted row+aux install each, host mirrors restored — no
-        prefill, no recompute. Runs after admissions so new requests get
-        first claim on slots (least-attained-service first)."""
-        resumed = 0
-        for rid in list(self._suspended):
-            e = self._suspended[rid]
-            if e["state"] != "ready":
-                continue
-            free = self.free_slots()
-            if not free:
-                break
-            slot = free[0]
-            pages = e["pages"]
-            trash = self.pool_pages
-            row = np.full((self.pages_per_slot,), trash, np.int32)
-            row[:len(pages)] = pages
-            self.stats["dispatches"] += 1
-            self.cache = self._tier_resume_fn(self.cache, e["aux"],
-                                              jnp.asarray(row), slot)
-            del self._suspended[rid]
-            self._slot_pages[slot] = pages
-            self._slot_extras[slot] = e["extras"]
-            self.active[slot] = e["req"]
-            self._restore_mirrors(slot, e["mirrors"])
-            self._slot_tick0[slot] = self._tick
-            self.tstats["resumes"] += 1
-            resumed += 1
-        return resumed
-
-    def _rotate(self):
-        """Time-slice rotation: when waiters exist (queued requests or
-        suspended entries), suspend the longest-resident decoding slot
-        whose quantum expired — spill-based preemption, so oversubscribed
-        workloads round-robin through the device pool instead of
-        re-prefilling (PR 8's evict) or starving the queue."""
-        waiters = [req.priority for req, _ in self.pending]
-        waiters += [e["req"].priority for e in self._suspended.values()
-                    if e["state"] != "spilling"]
-        if not waiters:
-            return
-        cap = max(waiters)
-        decoding = [s for s in range(self.slots)
-                    if self.active[s] is not None
-                    and s not in self._prefilling
-                    and s not in self._spilling_slots]
-        ready = any(e["state"] == "ready"
-                    for e in self._suspended.values())
-        if len(decoding) <= 1 and not ready:
-            return   # never idle the whole pool waiting on the PCIe link
-        expired = [(self._slot_tick0[s], s) for s in decoding
-                   if self._tick - self._slot_tick0[s] >= self.tier_cfg.quantum
-                   and self.active[s].priority <= cap]
-        if expired:
-            self._begin_suspend(min(expired)[1])
-
-    def _harvest_prefix(self):
-        """Warm-LRU prefix spill: when the plain free pool runs dry and
-        refcount-0 prefix pages are parked in the device cache, move the
-        coldest batch to the host tier's prefix store — they come back via
-        the admission-time tier probe instead of recompute. Pages stay
-        pinned until the host copy is durable; a failed spill re-indexes
-        them (nothing lost either way — these are cache copies)."""
-        if self.prefill_chunk is None or self.tier_faults.full():
-            return
-        if self._alloc.plain_free() > 0 or self._alloc.cached_free() == 0:
-            return
-        k = min(self.tier_cfg.harvest_batch, self.pages_per_slot,
-                self.tier.free_pages())
-        harvested = self._alloc.harvest(k)
-        if not harvested:
-            return
-        trash = self.pool_pages
-        ids = np.asarray([pid for pid, _ in harvested]
-                         + [trash] * (self.pages_per_slot - len(harvested)),
-                         np.int32)
-        self.stats["dispatches"] += 1
-        pay_dev, _ = self._tier_gather_fn(self.cache, jnp.asarray(ids), 0)
-        payload = tier_mod.trim_pages(tier_mod.staged_get(pay_dev),
-                                      len(harvested))
-        self._xfers.submit(tier_mod.PREFIX_SPILL, None, None,
-                           paged_mod.payload_nbytes(payload),
-                           slow=self.tier_faults.slow(),
-                           harvest=harvested, payload=payload)
-
-    def _finish_prefix_spill(self, t: tier_mod.TierTransfer):
-        for j, (pid, key) in enumerate(t.meta["harvest"]):
-            pg = tier_mod.slice_page(t.meta["payload"], j)
-            self.tier.put_prefix(key, pg, paged_mod.payload_crc(pg))
-        self._alloc.release([pid for pid, _ in t.meta["harvest"]])
-        self.tstats["prefix_spilled"] += len(t.meta["harvest"])
-        self.tstats["spill_bytes"] += t.nbytes
-
-    def _fail_prefix_spill(self, t: tier_mod.TierTransfer):
-        # the device copy never left: re-index the pages (release parks
-        # them back in the warm cache) and count the abort
-        for pid, key in t.meta["harvest"]:
-            self._alloc.register(key, pid)
-        self._alloc.release([pid for pid, _ in t.meta["harvest"]])
-        self.tstats["spill_aborts"] += 1
-
-    def _finish_prefix_fetch(self, t: tier_mod.TierTransfer):
-        """Tier prefix pages arrived for a chunk-prefilling slot: verify
-        CRCs, scatter into the slot's already-reserved fresh pages,
-        index them, and advance the prefill cursor past the covered
-        chunks. Any CRC mismatch drops the poisoned tier entries and
-        leaves the cursor alone — the chunks recompute into the same
-        pages, bitwise-identical."""
-        m = t.meta
-        slot = m["slot"]
-        ps = self._prefilling.get(slot)
-        if ps is None or ps.get("req") is not m["req"]:
-            return   # slot cancelled/recycled while the fetch flew
-        ps["tier_xfer"] = False
-        bad = [j for j, (pg, crc) in enumerate(m["stored"])
-               if paged_mod.payload_crc(pg) != crc]
-        if bad:
-            self.tstats["crc_failures"] += 1
-            for j in bad:
-                self.tier.drop_prefix(m["keys"][j])
-            return
-        trash = self.pool_pages
-        pages = m["pages"]
-        ids = np.asarray(pages + [trash] * (self.pages_per_slot
-                                            - len(pages)), np.int32)
-        payload = tier_mod.pad_pages(
-            tier_mod.concat_pages([pg for pg, _ in m["stored"]]),
-            self.pages_per_slot)
-        self.stats["dispatches"] += 1
-        self.cache = self._tier_scatter_fn(
-            self.cache, tier_mod.staged_put(payload), jnp.asarray(ids))
-        for j, key in enumerate(m["keys"]):
-            self._alloc.register(key, pages[j])
-        ps["next"] = m["end"]
-        self.tstats["prefix_fetched"] += len(pages)
-        self.tstats["fetch_bytes"] += t.nbytes
-
-    def _fail_prefix_fetch(self, t: tier_mod.TierTransfer):
-        ps = self._prefilling.get(t.meta["slot"])
-        if ps is not None and ps.get("req") is t.meta["req"]:
-            ps["tier_xfer"] = False   # cursor untouched: chunks recompute
-
-    def _advance_transfers(self):
-        done, failed = self._xfers.advance(self.tier_faults)
-        for t in done:
-            if t.kind == tier_mod.SPILL:
-                self._finish_spill(t)
-            elif t.kind == tier_mod.FETCH:
-                self._finish_fetch(t)
-            elif t.kind == tier_mod.PREFIX_SPILL:
-                self._finish_prefix_spill(t)
-            elif t.kind == tier_mod.PREFIX_FETCH:
-                self._finish_prefix_fetch(t)
-        for t in failed:
-            if t.kind == tier_mod.SPILL:
-                self._fail_spill(t)
-            elif t.kind == tier_mod.FETCH:
-                self._degrade(t.rid)
-            elif t.kind == tier_mod.PREFIX_SPILL:
-                self._fail_prefix_spill(t)
-            elif t.kind == tier_mod.PREFIX_FETCH:
-                self._fail_prefix_fetch(t)
-
     # -- decode -------------------------------------------------------------
     def _device_state(self) -> Dict[str, Any]:
         # built field-for-field like Model.init_decode_state (the canonical
@@ -1535,13 +1037,7 @@ class ServeEngine:
         st = dict(
             tokens=jnp.asarray(self._tokens),
             positions=jnp.asarray(self.positions),
-            # slots mid-chunked-prefill are occupied but not yet decoding,
-            # and mid-spill slots hold captured-in-flight pages: both are
-            # masked out of the fused loop
-            active=jnp.asarray(np.array(
-                [r is not None and i not in self._prefilling
-                 and i not in self._spilling_slots
-                 for i, r in enumerate(self.active)])),
+            active=jnp.asarray(self._decoding()),
             left=jnp.asarray(self._left),
             eos=jnp.asarray(self._eos),
             rngs=jnp.asarray(self._rngs),
@@ -1556,21 +1052,18 @@ class ServeEngine:
             st = jax.device_put(st, self._state_shardings)
         return st
 
+    def _decoding(self) -> np.ndarray:
+        """Per slot: occupied and not masked out of the decode dispatch."""
+        masked = self._masked_slots()
+        return np.array([r is not None and i not in masked
+                         for i, r in enumerate(self.active)])
+
     def step(self):
         """One scheduler tick: admit from the pending queue (priority
         order, page-aware, preempting lower-priority residents when a
         higher-priority arrival is blocked), advance one chunked-prefill
         slot by one chunk, then run one fused ``chunk``-step decode
         dispatch over the decoding slots.
-
-        Tiered engines prepend the tier phases: advance the transfer
-        clock (landing spills frees slots/pages, landing fetches readies
-        resumes), admit, resume fetched entries into leftover slots,
-        rotate a quantum-expired resident out for waiters, start
-        prefetches with leftover pages, and harvest cold prefix pages —
-        then decode as usual with spilling slots masked out. Fetches are
-        restarted once more after decode so pages freed by completions
-        this tick are already in flight by the next.
 
         The tick runs under the profiler span ``engine.step``, its phases
         under ``engine.admit`` (holding ``engine.prefill``,
@@ -1581,42 +1074,23 @@ class ServeEngine:
             self._step()
 
     def _step(self):
-        if self.tier is not None:
-            self._tick += 1
-            self.tier_faults.on_tick()
-            self._advance_transfers()
-            with _span("engine.admit"):
-                admitted = self._admit_pending()
-            resumed = self._resume_ready()
-            if (not admitted and not resumed and self.free_slots()
-                    and any(e["state"] in ("host", "fetching")
-                            for e in self._suspended.values())):
-                # a slot sat idle this tick because tiered KV wasn't back
-                # yet — the prefetch schedule exists to keep this at 0
-                self.tstats["prefetch_stalls"] += 1
-            self._rotate()
-            self._start_fetches()
-            self._harvest_prefix()
-            live = sum(len(p) for p in self._slot_pages) + sum(
-                e["n"] for e in self._suspended.values()
-                if e["state"] != "spilling")
-            self.tstats["peak_resident_pages"] = max(
-                self.tstats["peak_resident_pages"], live)
-        else:
-            with _span("engine.admit"):
-                self._admit_pending()
-        if self._prefilling:
-            # one chunk for one long-prompt admission per tick, so
-            # resident decode streams keep flowing between chunks (no
-            # TTFT cliff for requests queued behind a long prompt);
-            # slots whose prefix pages are inbound from the tier wait
-            runnable = [s for s in self._prefilling
-                        if not self._prefilling[s].get("tier_xfer")]
-            if runnable:
-                self._run_prefill_chunk(min(runnable))
-        if not any(r is not None and i not in self._prefilling
-                   and i not in self._spilling_slots
-                   for i, r in enumerate(self.active)):
+        with _span("engine.admit"):
+            self._admit_pending()
+        self._prefill_and_decode()
+
+    def _runnable_prefills(self) -> List[int]:
+        """Mid-chunked-prefill slots that may run a chunk this tick."""
+        return list(self._prefilling)
+
+    def _prefill_and_decode(self):
+        """Run one prefill chunk, then one fused decode dispatch."""
+        # one chunk for one long-prompt admission per tick, so resident
+        # decode streams keep flowing between chunks (no TTFT cliff for
+        # requests queued behind a long prompt)
+        runnable = self._runnable_prefills()
+        if runnable:
+            self._run_prefill_chunk(min(runnable))
+        if not self._decoding().any():
             return
         self.stats["dispatches"] += 1
         self.stats["decode_dispatches"] += 1
@@ -1645,23 +1119,20 @@ class ServeEngine:
         self.stats["decode_tokens"] += int(emitted.sum())
         if self._mla_kernel_decode:
             # each step's qpos: the dispatched positions, advanced by the
-            # lane's active steps before it; the kernel's rule computes
-            # pages 0..max(qpos, 0) // page of every lane
+            # lane's active steps before it
             qpos = (self.positions[:, None] + np.cumsum(emitted, axis=1)
                     - emitted)
-            last = np.minimum(np.maximum(qpos, 0) // self.page_size,
-                              self.pages_per_slot - 1)
-            self.stats["decode_live_pages"] += int((last + 1).sum())
+            self.stats["decode_live_pages"] += int(live_pages(
+                qpos, self.page_size, self.pages_per_slot).sum())
             self.stats["decode_grid_pages"] += (
                 self.slots * self.pages_per_slot * self.chunk)
         self.stats["drafts"] += int(host["drafts"])
         self.stats["accepted_drafts"] += int(host["accepted"])
         # copy: device_get arrays are read-only, mirrors are written on
-        # admit. Prefilling and mid-spill slots keep their host-written
-        # mirrors — their masked decode lanes carry stale device state
-        # (a spilling slot's authoritative mirrors ride its tier entry)
-        keep = np.array([i in self._prefilling or i in self._spilling_slots
-                         for i in range(self.slots)])
+        # admit. Masked slots keep their host-written mirrors — their
+        # decode lanes carry stale device state
+        masked = self._masked_slots()
+        keep = np.array([i in masked for i in range(self.slots)])
         self._tokens = np.where(keep, self._tokens,
                                 host["tokens"]).astype(np.int32)
         self.positions = np.where(keep, self.positions,
@@ -1679,12 +1150,6 @@ class ServeEngine:
             if not host["active"][i]:
                 r.done = True
                 self._release_slot(i)
-        if self.tier is not None:
-            # pages freed by completions this tick feed the prefetch
-            # schedule immediately: the fetch lands on next tick's clock
-            # advance, before the freed slot is rescheduled — the no-stall
-            # overlap the serve_bench gate asserts
-            self._start_fetches()
 
     def _release_slot(self, slot: int):
         """Free ``slot``: clear occupancy and (paged) drop one reference
@@ -1704,13 +1169,10 @@ class ServeEngine:
     def cancel(self, rid: int) -> bool:
         """Abort a request by id: drop it from the pending queue (an
         evicted-but-not-resumed continuation also releases the prefix
-        refcounts it retained), free its slot — mid-chunked-prefill or
+        refcounts it retained), or free its slot — mid-chunked-prefill or
         decoding alike (pages recycled; the lane is masked out of the
-        next dispatch) — or, on tiered engines, unwind whichever tier
-        state it is in (SPILLING/HOST/FETCHING/ready): device and host
-        pages both free and any in-flight transfer is dropped from the
-        clock. The Request object is left as-is — ``done`` stays False,
-        ``out`` keeps whatever was delivered — so a gateway can
+        next dispatch). The Request object is left as-is — ``done`` stays
+        False, ``out`` keeps whatever was delivered — so a gateway can
         re-dispatch it as a continuation. Returns False if unknown."""
         for i, (req, _) in enumerate(self.pending):
             if req.rid == rid:
@@ -1719,75 +1181,24 @@ class ServeEngine:
                 if held:
                     self._alloc.release(held)
                 return True
-        e = self._suspended.pop(rid, None)
-        if e is not None:
-            self._xfers.cancel(lambda t: t.rid == rid)
-            st = e["state"]
-            if st == "spilling":
-                # slot + device pages still held; row already trashed
-                slot = e["slot"]
-                del self._spilling_slots[slot]
-                self.tier.free(e["eid"])
-                self._release_slot(slot)
-            elif st == "host":
-                self.tier.free(e["eid"])
-            elif st == "fetching":
-                self.tier.free(e["eid"])
-                if e["fetch_pages"]:
-                    self._alloc.release(e["fetch_pages"])
-            else:   # ready: tier entry already freed, device pages held
-                self._alloc.release(e["pages"])
-            return True
         for slot, req in enumerate(self.active):
             if req is not None and req.rid == rid:
                 self._prefilling.pop(slot, None)
-                if self.tier is not None:
-                    self._xfers.cancel(lambda t: t.rid == rid)
                 self._release_slot(slot)
                 return True
         return False
 
     def pool_stats(self) -> Dict[str, Any]:
-        """Page-pool occupancy (zeros for dense engines). Tiered engines
-        add the host side so capacity dashboards see both levels of the
-        hierarchy."""
+        """Page-pool occupancy (zeros for dense engines)."""
         if not self.paged:
             return dict(pages_total=0, pages_free=0, pages_used=0,
                         occupancy=0.0)
         free = self.free_pages()
         used = self.pool_pages - free
-        out = dict(pages_total=self.pool_pages,
-                   pages_free=free, pages_used=used,
-                   occupancy=used / self.pool_pages if self.pool_pages
-                   else 0.0)
-        if self.tier is not None:
-            out.update(host_pages_total=self.tier.capacity_pages,
-                       host_pages_free=self.tier.free_pages(),
-                       host_occupancy=self.tier.occupancy())
-        return out
-
-    def tier_stats(self) -> Dict[str, Any]:
-        """Host-tier residency and transfer counters (``tstats`` plus the
-        live tier/clock occupancy). Meaningful only on tiered engines;
-        returns the zeroed counters otherwise so callers can read it
-        unconditionally."""
-        out = dict(self.tstats)
-        if self.tier is None:
-            out.update(host_pages_total=0, host_pages_used=0,
-                       host_pages_free=0, host_occupancy=0.0,
-                       host_prefix_pages=0, suspended=0,
-                       transfers_inflight=0, retries=0, timeouts=0)
-            return out
-        out.update(host_pages_total=self.tier.capacity_pages,
-                   host_pages_used=self.tier.used_pages(),
-                   host_pages_free=self.tier.free_pages(),
-                   host_occupancy=self.tier.occupancy(),
-                   host_prefix_pages=self.tier.prefix_pages(),
-                   suspended=len(self._suspended),
-                   transfers_inflight=len(self._xfers.inflight),
-                   retries=self._xfers.retries,
-                   timeouts=self._xfers.timeouts)
-        return out
+        return dict(pages_total=self.pool_pages,
+                    pages_free=free, pages_used=used,
+                    occupancy=used / self.pool_pages if self.pool_pages
+                    else 0.0)
 
     def prefix_stats(self) -> Dict[str, Any]:
         """Prefix-index effectiveness (zeros for dense / non-chunked
@@ -1797,14 +1208,9 @@ class ServeEngine:
         if not self.paged:
             return dict(lookups=0, hits=0, hit_rate=0.0, indexed_pages=0)
         lk = self._alloc.prefix_lookups
-        out = dict(lookups=lk, hits=self._alloc.prefix_hits,
-                   hit_rate=self._alloc.prefix_hits / lk if lk else 0.0,
-                   indexed_pages=self._alloc.indexed_pages())
-        if self.tier is not None:
-            out.update(tier_prefix_pages=self.tier.prefix_pages(),
-                       tier_prefix_evictions=self.tier.prefix_evictions,
-                       tier_prefix_fetched=self.tstats["prefix_fetched"])
-        return out
+        return dict(lookups=lk, hits=self._alloc.prefix_hits,
+                    hit_rate=self._alloc.prefix_hits / lk if lk else 0.0,
+                    indexed_pages=self._alloc.indexed_pages())
 
     def cache_bytes_per_token(self) -> float:
         """Attention-cache bytes per token of context capacity — the
@@ -1827,15 +1233,12 @@ class ServeEngine:
 
     def has_work(self) -> bool:
         """Whether another ``step()`` can make progress: queued or
-        resident requests, suspended entries parked in the host tier, or
-        transfers still on the clock. Drivers (``run_until_done``, the
-        gateway's idle check) must use this rather than pending/active
-        alone — a tiered engine with every request suspended looks idle
-        by the old test but still owes those requests their resumes."""
+        resident requests. Drivers (``run_until_done``, the gateway's
+        idle check) ask this rather than reading pending/active
+        themselves, so an engine that holds requests elsewhere can say
+        so."""
         return (bool(self.pending)
-                or any(r is not None for r in self.active)
-                or bool(self._suspended)
-                or bool(self._xfers.inflight))
+                or any(r is not None for r in self.active))
 
     def run_until_done(self, max_steps: int = 1000):
         """Drive chunks until every submitted/admitted request completes.

@@ -62,6 +62,7 @@ from repro.configs.base import ModelConfig
 from repro.serve.engine import AdmissionError, Request, ServeEngine
 from repro.serve.fault import (ReplicaCrash, ServeFaultInjector,
                                TierFaultAdapter)
+from repro.serve.tier import TieredServeEngine
 
 # Health states (registry) and circuit states (router), as plain strings
 # so they serialize straight into stats/bench rows.
@@ -155,7 +156,7 @@ class Replica:
         else:
             self.occupancy = slot_occ
             self.free_pages = 0
-        if eng.tier is not None:
+        if isinstance(eng, TieredServeEngine):
             ts = eng.tier_stats()
             self.host_occupancy = ts["host_occupancy"]
             self.host_free_pages = ts["host_pages_free"]
@@ -352,24 +353,26 @@ class Gateway:
                       "failed": 0, "replica_deaths": 0, "ticks": 0,
                       "dispatches": 0, "affinity_hits": 0}
         for i in range(replicas):
-            # tier faults ride the gateway clock: each replica's engine
-            # consults its own adapter, so ``pcie_slow:<r>`` degrades one
-            # replica's link while its peers transfer at full speed
-            tf = None
-            if injector is not None and host_tier_pages is not None:
-                tf = TierFaultAdapter(injector, replica=i,
-                                      clock=lambda: self.clock)
-            eng = ServeEngine(cfg, params=params, slots=slots,
-                              max_len=max_len, seed=seed + i, chunk=chunk,
-                              temperature=temperature, top_k=top_k,
-                              paged=paged, page_size=page_size,
-                              pool_pages=pool_pages,
-                              page_storage=page_storage,
-                              prefill_chunk=prefill_chunk,
-                              host_tier_pages=host_tier_pages,
-                              tier_config=tier_config,
-                              tier_faults=tf,
-                              max_pending=engine_max_pending)
+            kw = dict(params=params, slots=slots, max_len=max_len,
+                      seed=seed + i, chunk=chunk, temperature=temperature,
+                      top_k=top_k, paged=paged, page_size=page_size,
+                      pool_pages=pool_pages, page_storage=page_storage,
+                      prefill_chunk=prefill_chunk,
+                      max_pending=engine_max_pending)
+            if host_tier_pages is None:
+                eng = ServeEngine(cfg, **kw)
+            else:
+                # tier faults ride the gateway clock: each replica's
+                # engine consults its own adapter, so ``pcie_slow:<r>``
+                # degrades one replica's link while its peers transfer at
+                # full speed
+                tf = None
+                if injector is not None:
+                    tf = TierFaultAdapter(injector, replica=i,
+                                          clock=lambda: self.clock)
+                eng = TieredServeEngine(
+                    cfg, host_tier_pages=host_tier_pages,
+                    tier_config=tier_config, tier_faults=tf, **kw)
             if params is None:
                 params = eng.params       # one parameter set, N replicas
             self.registry.register(eng)

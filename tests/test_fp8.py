@@ -3,9 +3,49 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core import fp8
+
+# every finite e4m3 value, ascending
+_E4M3_GRID = np.unique(np.arange(256, dtype=np.uint8).view(fp8.E4M3)
+                       .astype(np.float64))
+_E4M3_GRID = _E4M3_GRID[np.isfinite(_E4M3_GRID)]
+
+
+def _scale_invariance_faults(quantize, x: np.ndarray, scale: float) -> int:
+    """Elements of ``x`` where the tile quantizer ``quantize`` is not
+    relative. Quantizing ``x * scale`` must give each live tile ``scale``
+    times the tile scale (to fp32 rounding) and every element the same
+    e4m3 code, except where ``x / tile_scale`` sits on a rounding tie
+    between two neighbouring codes: the rounding of the fp32 scale may
+    then send it to either one."""
+    d = x.shape[-1]
+
+    def per_elem(a):   # (..., tiles) -> (..., d)
+        return np.repeat(np.asarray(a, np.float64), fp8.TILE, axis=-1)[..., :d]
+
+    (q1, s1), (q2, s2) = (quantize(jnp.asarray(v)) for v in (x, x * scale))
+    q1, q2 = np.asarray(q1, np.float64), np.asarray(q2, np.float64)
+    s1, s2 = per_elem(s1), per_elem(s2)
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, (-d) % fp8.TILE)]
+    amax = np.abs(np.pad(x, pad)).reshape(x.shape[:-1] + (-1, fp8.TILE))
+    live = per_elem(amax.max(-1)) > 0      # an all-zero tile keeps the floor
+    bad_scale = live & (np.abs(s2 / (s1 * scale) - 1) > 1e-6)
+    t = x / s1                             # the value the code rounds
+    neighbours = np.abs(np.searchsorted(_E4M3_GRID, q1)
+                        - np.searchsorted(_E4M3_GRID, q2)) == 1
+    tie = np.abs(t - (q1 + q2) / 2) <= 1e-5 * np.abs(t)
+    bad_code = (q1 != q2) & ~(neighbours & tie)
+    return int((bad_scale | bad_code).sum())
+
+
+def _off_by_a_binade(x):
+    """A broken tile quantizer: right codes, but once a tile's amax passes
+    1 its scale doubles, so it dequantizes a binade too high."""
+    q, s = fp8.quantize_tilewise(x)
+    amax = s * fp8.E4M3_MAX
+    return q, jnp.where(amax > 1.0, 2 * s, s)
 
 
 class TestQuantization:
@@ -35,23 +75,24 @@ class TestQuantization:
         assert bool((fp8.dequant_tilewise(q, s) == 0).all())
 
     @given(st.integers(1, 4), st.integers(1, 300), st.floats(0.01, 1e4))
+    @example(3, 3, 0.85)
+    @example(3, 3, 3396.96528666261)
     @settings(max_examples=20, deadline=None)
     def test_property_scale_invariance(self, rows, cols, scale):
-        """Quantization error is relative: scaling input ~scales output.
-        The tile scale itself rounds in fp32, so grid points can shift by
-        one quantization step on ties — bound the violating fraction and
-        the violation magnitude instead of exact equality."""
+        """Quantization is relative: scaling the input scales each tile
+        scale and keeps every e4m3 code, but for values on a rounding tie
+        (at (3, 3, 0.85), x = 0.75 gives x / scale = 336, halfway between
+        320 and 352), which may land on either neighbour."""
         x = np.linspace(-1, 1, rows * cols, dtype=np.float32).reshape(
             rows, cols)
-        y1 = np.asarray(fp8.qdq_tile(jnp.asarray(x))) * scale
-        y2 = np.asarray(fp8.qdq_tile(jnp.asarray(x * scale)))
-        # E4M3 has 3 mantissa bits: ULP(v) ~ v/8 at the top of each
-        # binade, so a 1-ULP grid shift near amax can move a value by
-        # ~scale/8 (amax(|x|) = 1 here)
-        qstep = scale / 8.0
-        bad = np.abs(y1 - y2) > (2e-2 * np.abs(y2) + 0.25 * qstep)
-        assert bad.mean() <= 0.05, bad.mean()
-        assert np.abs(y1 - y2).max() <= 1.5 * qstep
+        assert _scale_invariance_faults(fp8.quantize_tilewise, x,
+                                        scale) == 0
+
+    @pytest.mark.parametrize("scale", [1.5, 3396.96528666261])
+    def test_scale_invariance_check_fails_a_broken_quantizer(self, scale):
+        x = np.linspace(-1, 1, 9, dtype=np.float32).reshape(3, 3)
+        assert _scale_invariance_faults(_off_by_a_binade, x, scale) > 0
+        assert _scale_invariance_faults(fp8.quantize_tilewise, x, scale) == 0
 
     def test_linear_grads_close_to_exact(self, rng):
         k1, k2, k3 = jax.random.split(rng, 3)

@@ -29,7 +29,7 @@ def _dsv3(wide: bool):
             cfg, d_model=256,
             mla=dataclasses.replace(cfg.mla, q_lora_rank=256,
                                     v_head_dim=64))
-    assert cfg.fp8 and cfg.fp8_impl == "ref"
+    assert cfg.fp8
     return cfg
 
 

@@ -371,6 +371,15 @@ def _mla_sample(kind, seed):
 
 
 class TestPagedMLALivePages:
+    def test_host_rule_is_the_kernels(self):
+        """``live_pages`` (the engine's counter) counts the pages up to
+        the kernel's ``_last_live_page`` for every query position."""
+        page, pp = 8, 4
+        qpos = np.arange(-3, pp * page + 9, dtype=np.int32)
+        last = jax.vmap(lambda q: pa._last_live_page(q, page, pp))(qpos)
+        np.testing.assert_array_equal(pa.live_pages(qpos, page, pp),
+                                      np.asarray(last) + 1)
+
     @pytest.mark.parametrize("kind,seed", MLA_SAMPLES)
     def test_bitwise_equal_to_full_walk(self, kind, seed):
         """Skipping dead pages changes no bit of a finite pool's output."""

@@ -141,7 +141,7 @@ def test_live_page_counters_stay_zero_off_the_kernel(dsv3_cfg, kw):
 
 
 def test_decode_chunk_hlo_carries_the_device_scopes(dsv3_cfg):
-    cfg = dataclasses.replace(dsv3_cfg, fp8=True, fp8_impl="ref")
+    cfg = dataclasses.replace(dsv3_cfg, fp8=True)
     eng = _paged(cfg)
     _serve(eng, [5], max_new=2)
     # the optimized HLO: its ops' op_name metadata is what the device

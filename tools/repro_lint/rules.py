@@ -142,13 +142,15 @@ class JitContractRule(Rule):
     name = "R2-jit-contract"
     doc = ("hot-path jax.jit must pass donate_argnums (and out_shardings "
            "in the meshed engine) or carry a justified waiver")
-    include = ("*serve/engine.py", "*serve/disagg.py", "*train/trainer.py")
+    include = ("*serve/engine.py", "*serve/tier.py", "*serve/disagg.py",
+               "*train/trainer.py")
     exclude = TESTS
 
     def check_file(self, src: SourceFile,
                    project: Project) -> Iterable[Diagnostic]:
         out: List[Diagnostic] = []
-        meshed_engine = src.rel.endswith("serve/engine.py")
+        meshed_engine = src.rel.endswith(("serve/engine.py",
+                                          "serve/tier.py"))
         for call in A.walk_calls(src.tree):
             if A.call_name(call).rsplit(".", 1)[-1] != "jit":
                 continue
